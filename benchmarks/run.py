@@ -17,6 +17,7 @@ import json
 import os
 import re
 import sys
+import traceback
 
 _BYTES_RE = re.compile(r"(?:^|;)bytes=(\d+)")
 
@@ -34,13 +35,17 @@ def write_comm_json(rows, path: str = "BENCH_comm.json") -> None:
         f.write("\n")
 
 
-def main() -> None:
+def main() -> int:
+    """Run every module, past failures; return how many modules failed."""
     from benchmarks import bench_cohort, bench_comm, bench_efbv
     from benchmarks import bench_faults, bench_fedp3, bench_hier
     from benchmarks import bench_kernels, bench_scafflix, bench_scafflix_nn
     from benchmarks import bench_serve, bench_sppm, bench_symwanda
     from benchmarks.common import emit, module_trace, now_s, trace_dir
     from repro.obs import trace as obs_trace
+    from repro.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     modules = [
         ("comm(codecs/ledger/topology)", bench_comm),
@@ -64,6 +69,7 @@ def main() -> None:
         id(bench_serve): ("BENCH_SERVE_JSON", "BENCH_serve.json"),
     }
     print("name,us_per_call,derived")
+    failed = 0
     for label, mod in modules:
         t0 = now_s()
         short = mod.__name__.rsplit(".", 1)[-1].removeprefix("bench_")
@@ -83,9 +89,12 @@ def main() -> None:
                 write_comm_json(rows, path)
                 print(f"# {label} rows -> {path}", file=sys.stderr)
         except Exception as e:  # noqa: BLE001 — keep the suite going
+            failed += 1
+            traceback.print_exc(file=sys.stderr)
             print(f"{label}/ERROR,0,{type(e).__name__}:{e}", file=sys.stderr)
         print(f"# {label} done in {now_s()-t0:.1f}s", file=sys.stderr)
+    return failed
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(1 if main() else 0)

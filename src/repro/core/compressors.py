@@ -69,6 +69,9 @@ class Compressor:
     flatten: bool = True
     # wire format for repro.comm.codecs.encode/decode (None -> dense)
     wire: Optional[WireSpec] = None
+    # fn runs a Pallas kernel, which GSPMD cannot partition: on a mesh each
+    # device applies it to its own worker groups (distributed._map_groups)
+    pallas: bool = False
 
     def __call__(self, key, x):
         if not self.flatten:
@@ -310,8 +313,9 @@ def qsgd_sharded(bits: int = 8, block: int = 256, stochastic: bool = True) -> Co
                       wire=WireSpec("quant", block=block, bits=bits, axis="last"))
 
 
-def qsgd_kernel(bits: int = 8, interpret: bool = True) -> Compressor:
-    """qsgd backed by the fused Pallas quantize-dequantize kernel."""
+def qsgd_kernel(bits: int = 8, interpret: Optional[bool] = None) -> Compressor:
+    """qsgd backed by the fused Pallas quantize-dequantize kernel
+    (``interpret=None``: compiled or interpreted per kernels/backend.py)."""
     from repro.kernels.ops import quantize_dequantize
     from repro.kernels.quant8 import QBLOCK
 
@@ -322,7 +326,8 @@ def qsgd_kernel(bits: int = 8, interpret: bool = True) -> Compressor:
 
     return Compressor(f"qsgd_kernel({bits}b)", fn, eta=0.0,
                       omega=QBLOCK / (4.0 * s * s), bits_per_dim=float(bits),
-                      wire=WireSpec("quant", block=QBLOCK, bits=bits, axis="kernel"))
+                      wire=WireSpec("quant", block=QBLOCK, bits=bits, axis="kernel"),
+                      pallas=True)
 
 
 _REGISTRY = {

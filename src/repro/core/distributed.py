@@ -220,6 +220,23 @@ def fused_apply(fn, delta_b, d: int):
     return out.reshape(delta_b.shape)
 
 
+def _map_groups(c: Compressor, keys, x):
+    """``c`` vmapped over the leading worker-group axis of ``x``.  Traced
+    under a mesh (``jax.set_mesh``), a Pallas compressor runs inside a
+    shard_map over the mesh's data axes, each device on its own groups:
+    GSPMD cannot partition a Mosaic kernel."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or not c.pallas:
+        return jax.vmap(c)(keys, x)
+    from jax.sharding import PartitionSpec as P
+
+    from repro.sharding.rules import data_axes
+
+    spec = P(data_axes(mesh))
+    return jax.shard_map(jax.vmap(c), in_specs=(spec, spec), out_specs=spec,
+                         check_vma=False)(keys, x)
+
+
 def _fused_compress(c: Compressor, keys, delta_b, d: int):
     """One fused compressor pass over the bucketed (G, n_buckets, B) delta.
 
@@ -230,7 +247,7 @@ def _fused_compress(c: Compressor, keys, delta_b, d: int):
     sharding-safe ones stay on the per-leaf path.)
     """
     return fused_apply(
-        lambda core: jax.vmap(lambda k, v: c(k, v))(keys, core), delta_b, d)
+        lambda core: _map_groups(c, keys, core), delta_b, d)
 
 
 def _efbv_sync_leaves(key, grads_g, state: SyncState, c: Compressor,
@@ -246,7 +263,7 @@ def _efbv_sync_leaves(key, grads_g, state: SyncState, c: Compressor,
         lkey = jax.random.fold_in(key, li)
         keys = jax.random.split(lkey, G)
         delta = g.astype(jnp.float32) - h
-        d_i = jax.vmap(lambda k, v: c(k, v))(keys, delta)
+        d_i = _map_groups(c, keys, delta)
         d = jnp.mean(d_i, axis=0)
         new_h.append(h + lam * d_i)
         g_est.append(hb + nu * d)

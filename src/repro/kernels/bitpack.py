@@ -17,17 +17,19 @@ Two kernel families back the payload codecs:
     produces what goes on the wire, instead of quantize -> dequantize ->
     re-quantize on the host.
 
-Pure-jnp oracles live in kernels/ref.py; ``interpret`` defaults to True for
-the CPU validation container and is flipped off on real TPUs by the launcher.
+Pure-jnp oracles live in kernels/ref.py; whether a kernel compiles or runs in
+the interpreter is decided by kernels/backend.py.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.backend import resolve
 from repro.kernels.quant8 import QBLOCK, TILE_ROWS
 
 PACK_BITS = 32      # bits per packed word (uint32)
@@ -38,9 +40,13 @@ PACK_LANES = 128    # lane tile for the word axis
 # mask bitpack
 # ---------------------------------------------------------------------------
 def _pack_kernel(mask_ref, out_ref):
-    bits = mask_ref[...].astype(jnp.uint32)
-    shifts = jax.lax.broadcasted_iota(jnp.uint32, bits.shape, dimension=0)
-    out_ref[...] = jnp.sum(bits << shifts, axis=0, keepdims=True).astype(jnp.uint32)
+    # Mosaic has no reduction over unsigned integers: sum in int32.  The 32
+    # shifted bits are disjoint, so the wrapping int32 sum has exactly the
+    # bit pattern of their OR (bit 31 lands in the sign bit).
+    bits = mask_ref[...].astype(jnp.int32)
+    shifts = jax.lax.broadcasted_iota(jnp.int32, bits.shape, dimension=0)
+    words = jnp.sum(bits << shifts, axis=0, keepdims=True)
+    out_ref[...] = jax.lax.bitcast_convert_type(words, jnp.uint32)
 
 
 def _unpack_kernel(words_ref, out_ref):
@@ -50,7 +56,7 @@ def _unpack_kernel(words_ref, out_ref):
                     & jnp.uint32(1)).astype(jnp.uint32)
 
 
-def pack_mask_2d(mask2d: jax.Array, interpret: bool = True) -> jax.Array:
+def pack_mask_2d(mask2d: jax.Array, interpret: Optional[bool] = None) -> jax.Array:
     """(32, C) {0,1} mask -> (1, C) uint32 words; C % PACK_LANES == 0."""
     rows, c = mask2d.shape
     assert rows == PACK_BITS and c % PACK_LANES == 0, (mask2d.shape,)
@@ -61,11 +67,11 @@ def pack_mask_2d(mask2d: jax.Array, interpret: bool = True) -> jax.Array:
         in_specs=[pl.BlockSpec((PACK_BITS, PACK_LANES), lambda j: (0, j))],
         out_specs=pl.BlockSpec((1, PACK_LANES), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((1, c), jnp.uint32),
-        interpret=interpret,
+        interpret=resolve(interpret),
     )(mask2d.astype(jnp.uint32))
 
 
-def unpack_mask_2d(words2d: jax.Array, interpret: bool = True) -> jax.Array:
+def unpack_mask_2d(words2d: jax.Array, interpret: Optional[bool] = None) -> jax.Array:
     """(1, C) uint32 words -> (32, C) {0,1} uint32 mask."""
     one, c = words2d.shape
     assert one == 1 and c % PACK_LANES == 0, (words2d.shape,)
@@ -76,7 +82,7 @@ def unpack_mask_2d(words2d: jax.Array, interpret: bool = True) -> jax.Array:
         in_specs=[pl.BlockSpec((1, PACK_LANES), lambda j: (0, j))],
         out_specs=pl.BlockSpec((PACK_BITS, PACK_LANES), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((PACK_BITS, c), jnp.uint32),
-        interpret=interpret,
+        interpret=resolve(interpret),
     )(words2d)
 
 
@@ -99,7 +105,7 @@ def _unpack_dequant_kernel(q_ref, scale_ref, out_ref):
 
 
 def quant_pack_2d(x2d: jax.Array, noise2d: jax.Array, bits: int = 8,
-                  interpret: bool = True):
+                  interpret: Optional[bool] = None):
     """(rows, QBLOCK) -> (int8 plane (rows, QBLOCK), fp32 scales (rows, 1)).
 
     Same math as quant8.quant_dequant_2d but emits the wire planes; the two
@@ -124,12 +130,12 @@ def quant_pack_2d(x2d: jax.Array, noise2d: jax.Array, bits: int = 8,
             jax.ShapeDtypeStruct((rows, qb), jnp.int8),
             jax.ShapeDtypeStruct((rows, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve(interpret),
     )(x2d, noise2d)
 
 
 def unpack_dequant_2d(q2d: jax.Array, scales: jax.Array, out_dtype=jnp.float32,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: Optional[bool] = None) -> jax.Array:
     """Inverse of quant_pack_2d: int8 plane + (rows, 1) scales -> dense."""
     rows, qb = q2d.shape
     assert qb == QBLOCK and rows % TILE_ROWS == 0, (q2d.shape,)
@@ -144,5 +150,5 @@ def unpack_dequant_2d(q2d: jax.Array, scales: jax.Array, out_dtype=jnp.float32,
         ],
         out_specs=pl.BlockSpec((TILE_ROWS, QBLOCK), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, qb), out_dtype),
-        interpret=interpret,
+        interpret=resolve(interpret),
     )(q2d, scales)

@@ -13,10 +13,13 @@ Tiles: (TILE_R, TILE_C) of the (d_in, d_out) weight; groups run along d_in
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.backend import resolve
 
 TILE_R = 128
 TILE_C = 128
@@ -43,7 +46,7 @@ def _nm_kernel(w_ref, s_ref, out_ref, mask_ref, *, n: int, m: int):
 
 
 def nm_prune_2d(w: jax.Array, scores: jax.Array, n: int = 2, m: int = 4,
-                interpret: bool = True):
+                interpret: Optional[bool] = None):
     """w, scores: (d_in, d_out) with d_in % TILE_R == 0, d_out % TILE_C == 0.
     Returns (pruned w, mask)."""
     d_in, d_out = w.shape
@@ -59,5 +62,5 @@ def nm_prune_2d(w: jax.Array, scores: jax.Array, n: int = 2, m: int = 4,
             jax.ShapeDtypeStruct(w.shape, w.dtype),
             jax.ShapeDtypeStruct(w.shape, w.dtype),
         ],
-        interpret=interpret,
+        interpret=resolve(interpret),
     )(w, scores)

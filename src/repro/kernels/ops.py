@@ -8,12 +8,13 @@ row/col sums, symwanda normalizers), and expose drop-in backends:
   * ``prune_nm``             — N:M backend for core/symwanda.mask_nm
   * ``prune_scored``         — fused score+mask backend for core/symwanda.prune
 
-``interpret`` defaults to True (CPU validation container); on a real TPU
-deployment it is flipped off by the launcher.
+``interpret=None`` leaves the choice to kernels/backend.py: compiled on a
+TPU, interpreted on the CPU.  Passing True or False overrides it.
 """
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -29,7 +30,7 @@ from repro.kernels import ref as _ref
 # bitpack (repro.comm wire formats)
 # ---------------------------------------------------------------------------
 @partial(jax.jit, static_argnames=("interpret",))
-def pack_bits(mask: jax.Array, interpret: bool = True) -> jax.Array:
+def pack_bits(mask: jax.Array, interpret: Optional[bool] = None) -> jax.Array:
     """Flat {0,1} mask (d,) -> uint32 word stream (ceil(d/32),).
 
     Bit layout: with W = ceil(d/32), bit j of word w is mask[j*W + w] — the
@@ -46,7 +47,7 @@ def pack_bits(mask: jax.Array, interpret: bool = True) -> jax.Array:
 
 
 @partial(jax.jit, static_argnames=("d", "interpret"))
-def unpack_bits(words: jax.Array, d: int, interpret: bool = True) -> jax.Array:
+def unpack_bits(words: jax.Array, d: int, interpret: Optional[bool] = None) -> jax.Array:
     """Inverse of pack_bits: (ceil(d/32),) uint32 -> (d,) {0,1} uint32."""
     w = words.shape[0]
     assert w == -(-d // _bp.PACK_BITS), (w, d)
@@ -74,7 +75,7 @@ def _quant_tiles(x: jax.Array, key: jax.Array):
 
 @partial(jax.jit, static_argnames=("bits", "interpret"))
 def quantize_pack(x: jax.Array, key: jax.Array, bits: int = 8,
-                  interpret: bool = True):
+                  interpret: Optional[bool] = None):
     """Flat/any-shape tensor -> (int8 plane (rows, QBLOCK), scales (rows, 1)).
 
     Shape plumbing (padding, noise draw) matches quantize_dequantize exactly,
@@ -87,7 +88,7 @@ def quantize_pack(x: jax.Array, key: jax.Array, bits: int = 8,
 
 @partial(jax.jit, static_argnames=("d", "interpret"))
 def unpack_dequantize(q: jax.Array, scales: jax.Array, d: int,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: Optional[bool] = None) -> jax.Array:
     """Inverse of quantize_pack: wire planes -> flat (d,) float32 tensor."""
     out = _bp.unpack_dequant_2d(q, scales, interpret=interpret)
     return out.reshape(-1)[:d]
@@ -95,7 +96,7 @@ def unpack_dequantize(q: jax.Array, scales: jax.Array, d: int,
 
 @partial(jax.jit, static_argnames=("bits", "interpret"))
 def stream_quantize_pack(x: jax.Array, key: jax.Array, bits: int = 8,
-                         interpret: bool = True):
+                         interpret: Optional[bool] = None):
     """quantize_pack via the double-buffered streaming DMA ring
     (kernels/stream.py).  Identical shape plumbing and noise draw, so the
     wire planes are bit-identical to ``quantize_pack``'s."""
@@ -126,7 +127,7 @@ def nibble_unpack(packed: jax.Array, n: int) -> jax.Array:
 # ---------------------------------------------------------------------------
 @partial(jax.jit, static_argnames=("bits", "interpret"))
 def quantize_dequantize(x: jax.Array, key: jax.Array, bits: int = 8,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: Optional[bool] = None) -> jax.Array:
     """Blockwise absmax quantize-dequantize of an arbitrary-shape tensor."""
     padded, noise, d = _quant_tiles(x, key)
     out = _q8.quant_dequant_2d(padded, noise, bits=bits, interpret=interpret)
@@ -146,7 +147,7 @@ def _pad2d(a, tr, tc):
 
 @partial(jax.jit, static_argnames=("n", "m", "interpret"))
 def prune_nm(w: jax.Array, scores: jax.Array, n: int = 2, m: int = 4,
-             interpret: bool = True):
+             interpret: Optional[bool] = None):
     """(d_in, d_out) N:M prune by score; returns (pruned, mask)."""
     wp, r, c = _pad2d(w, _nm.TILE_R, _nm.TILE_C)
     # padded score rows must never win: fill with -inf
@@ -162,7 +163,7 @@ def prune_nm(w: jax.Array, scores: jax.Array, n: int = 2, m: int = 4,
 @partial(jax.jit, static_argnames=("mode", "sparsity", "interpret"))
 def prune_scored(w: jax.Array, X: jax.Array, mode: str = "wanda",
                  sparsity: float = 0.5, alpha: float = 0.5, beta: float = 0.5,
-                 interpret: bool = True):
+                 interpret: Optional[bool] = None):
     """Fused score+mask prune of w (d_in, d_out) with calibration X (T, d_in).
 
     Per-output thresholds come from a top-k over the (recomputed-on-the-fly)

@@ -15,10 +15,13 @@ explicit functional PRNG keeps the kernel portable and the oracle exact).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.backend import resolve
 
 TILE_ROWS = 8
 QBLOCK = 512  # quantization block size (multiple of 128 lanes)
@@ -35,7 +38,7 @@ def _quant_kernel(x_ref, noise_ref, out_ref, *, s_levels: int):
 
 
 def quant_dequant_2d(x2d: jax.Array, noise2d: jax.Array, bits: int = 8,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: Optional[bool] = None) -> jax.Array:
     """x2d, noise2d: (rows, QBLOCK). rows must be a multiple of TILE_ROWS."""
     rows, qb = x2d.shape
     assert qb == QBLOCK and rows % TILE_ROWS == 0, (x2d.shape,)
@@ -50,5 +53,5 @@ def quant_dequant_2d(x2d: jax.Array, noise2d: jax.Array, bits: int = 8,
         ],
         out_specs=pl.BlockSpec((TILE_ROWS, QBLOCK), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x2d.shape, x2d.dtype),
-        interpret=interpret,
+        interpret=resolve(interpret),
     )(x2d, noise2d)

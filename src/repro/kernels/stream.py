@@ -16,23 +16,29 @@ Pipeline per tile (slot = k % 2):
     in-DMA[k+1] start ->  wait in-DMA[k] -> wait out-DMA[k-2] (slot free)
                        -> quantize-pack tile k in VMEM -> out-DMA[k] start
 
-Interpret mode (the CPU validation container) executes the same semaphore
+Interpret mode (on the CPU) executes the same semaphore
 structure serially; the pure-jnp oracle is ``ref.stream_quant_pack_ref`` and
 the jit wrapper with shape plumbing is ``ops.stream_quantize_pack``.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import resolve
 from repro.kernels.quant8 import QBLOCK, TILE_ROWS
 from repro.obs.trace import annotate
 
 N_SLOTS = 2  # double buffering
+# Mosaic slices VMEM only along whole 128-lane tiles, so each tile's
+# (TILE_ROWS, 1) scale column is staged and copied out broadcast across one
+# lane tile; the wrapper keeps lane 0.
+SCALE_LANES = 128
 
 
 def _stream_kernel(x_hbm, noise_hbm, q_hbm, scale_hbm, *, n_tiles: int,
@@ -81,7 +87,7 @@ def _stream_kernel(x_hbm, noise_hbm, q_hbm, scale_hbm, *, n_tiles: int,
             scale = jnp.where(scale == 0.0, 1.0, scale)
             q = jnp.floor(x / scale + n_buf[slot])   # noise in [0,1): stochastic
             q_buf[slot] = jnp.clip(q, -s_levels, s_levels).astype(jnp.int8)
-            s_buf[slot] = scale
+            s_buf[slot] = jnp.broadcast_to(scale, (TILE_ROWS, SCALE_LANES))
 
             for dma in out_dmas(slot, k):
                 dma.start()
@@ -101,14 +107,14 @@ def _stream_kernel(x_hbm, noise_hbm, q_hbm, scale_hbm, *, n_tiles: int,
         x_buf=pltpu.VMEM((N_SLOTS, TILE_ROWS, QBLOCK), x_hbm.dtype),
         n_buf=pltpu.VMEM((N_SLOTS, TILE_ROWS, QBLOCK), jnp.float32),
         q_buf=pltpu.VMEM((N_SLOTS, TILE_ROWS, QBLOCK), jnp.int8),
-        s_buf=pltpu.VMEM((N_SLOTS, TILE_ROWS, 1), jnp.float32),
+        s_buf=pltpu.VMEM((N_SLOTS, TILE_ROWS, SCALE_LANES), jnp.float32),
         in_sems=pltpu.SemaphoreType.DMA((N_SLOTS, 2)),
         out_sems=pltpu.SemaphoreType.DMA((N_SLOTS, 2)),
     )
 
 
 def stream_quant_pack_2d(x2d: jax.Array, noise2d: jax.Array, bits: int = 8,
-                         interpret: bool = True):
+                         interpret: Optional[bool] = None):
     """(rows, QBLOCK) -> (int8 plane (rows, QBLOCK), fp32 scales (rows, 1)).
 
     Same math (and bit-identical planes) as ``bitpack.quant_pack_2d``; the
@@ -118,15 +124,16 @@ def stream_quant_pack_2d(x2d: jax.Array, noise2d: jax.Array, bits: int = 8,
     assert qb == QBLOCK and rows % TILE_ROWS == 0, (x2d.shape,)
     s = 2 ** (bits - 1) - 1
     n_tiles = rows // TILE_ROWS
-    return pl.pallas_call(
+    q, scales = pl.pallas_call(
         functools.partial(_stream_kernel, n_tiles=n_tiles, s_levels=s),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
-                   pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec(memory_space=pl.ANY)],
         out_shape=[
             jax.ShapeDtypeStruct((rows, qb), jnp.int8),
-            jax.ShapeDtypeStruct((rows, 1), jnp.float32),
+            jax.ShapeDtypeStruct((rows, SCALE_LANES), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve(interpret),
     )(x2d, noise2d)
+    return q, scales[:, :1]

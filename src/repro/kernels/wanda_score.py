@@ -18,10 +18,13 @@ cheap column pass) and broadcast into the kernel.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.backend import resolve
 
 TILE_R = 128
 TILE_C = 128
@@ -60,7 +63,7 @@ def _wanda_kernel(w_ref, xn_ref, tau_ref, rs_ref, cs_ref, out_ref, mask_ref,
 def wanda_prune_2d(w: jax.Array, xnorm: jax.Array, tau: jax.Array,
                    mode: str = "wanda", alpha: float = 0.5, beta: float = 0.5,
                    rowsum: jax.Array = None, colsum: jax.Array = None,
-                   ynorm: jax.Array = None, interpret: bool = True):
+                   ynorm: jax.Array = None, interpret: Optional[bool] = None):
     """w (d_in, d_out); xnorm (d_in,); tau (d_out,). RIA: rowsum (d_in,),
     colsum (d_out,). SymWanda: ynorm (d_out,) + normalizers packed by ops.py.
     Returns (pruned w, mask)."""
@@ -97,6 +100,6 @@ def wanda_prune_2d(w: jax.Array, xnorm: jax.Array, tau: jax.Array,
             jax.ShapeDtypeStruct(w.shape, w.dtype),
             jax.ShapeDtypeStruct(w.shape, w.dtype),
         ],
-        interpret=interpret,
+        interpret=resolve(interpret),
     )(w, xnorm.reshape(1, d_in).astype(jnp.float32),
       tau.reshape(1, d_out).astype(jnp.float32), rs, cs)

@@ -86,7 +86,7 @@ def _lower_variant(cfg, mesh, shape, kind: str, n_periods: int, block_k: int,
 def corrected_costs(arch_cfg: ModelConfig, mesh, shape_name: str,
                     sync_mode: str = "dense", grad_accum: int = 1) -> Dict:
     """Returns {'raw_keys': {...}, 'corrected': {...}, 'model': {...}}."""
-    from repro.launch import dryrun as dr
+    from repro.launch import sharded as dr
 
     shape = INPUT_SHAPES[shape_name]
     builders = {"train": dr.build_train_lowering,

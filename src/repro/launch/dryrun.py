@@ -15,224 +15,19 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 import argparse
 import json
+import sys
 import traceback
 
-import jax
-import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
-
-from repro.configs.base import INPUT_SHAPES, SyncConfig, TrainConfig, get_config, list_configs
+from repro.configs.base import INPUT_SHAPES, get_config, list_configs
 from repro.launch import hlo_analysis as hlo
 from repro.launch.mesh import make_production_mesh
-from repro.launch.specs import input_specs, skip_reason
-from repro.models import cache_specs, init_params, model_dtype
+from repro.launch.sharded import (
+    build_decode_lowering, build_prefill_lowering, build_train_lowering)
+from repro.launch.specs import skip_reason
 from repro.obs.trace import wall_s
-from repro.sharding.rules import (
-    batch_specs, cache_pspecs, data_axes, opt_state_specs, param_specs)
-from repro.training.steps import init_train_state, make_train_step, make_prefill_step, make_decode_step
 from repro.utils.logging import get_logger
 
 log = get_logger("dryrun")
-
-
-def _sharding(mesh, spec_tree):
-    return jax.tree_util.tree_map(
-        lambda s: NamedSharding(mesh, s), spec_tree,
-        is_leaf=lambda x: isinstance(x, P))
-
-
-def _abstract(tree):
-    return jax.tree_util.tree_map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
-
-
-def auto_grad_accum(cfg, shape, n_data: int, width_shards: int = 16) -> int:
-    """Microbatch count so remat residuals + logits fit HBM: scale with the
-    per-device token load and residual width."""
-    local_batch = max(1, shape.global_batch // n_data)
-    resid_gb = (cfg.num_layers * local_batch * shape.seq_len * cfg.d_model * 2
-                / width_shards / 1e9)  # model-sharded bf16 stack
-    accum = 1
-    while resid_gb / accum > 1.0 and accum < local_batch:
-        accum *= 2
-    return accum
-
-
-def build_train_lowering(cfg, mesh, shape, sync_mode="dense", compressor="qsgd",
-                         sync_period=4, remat="full", grad_accum=None):
-    daxes = data_axes(mesh)
-    n_groups = 1
-    for a in daxes:
-        n_groups *= mesh.shape[a]
-    n_pods = mesh.shape.get("pod", 1)
-    if grad_accum is None:
-        from repro.sharding import rules as _r
-        if sync_mode != "dense":
-            grad_accum = 1
-        elif _r.NO_TP:
-            grad_accum = auto_grad_accum(
-                cfg, shape, n_groups * mesh.shape["model"], width_shards=1)
-        else:
-            grad_accum = auto_grad_accum(cfg, shape, n_groups)
-
-    tc = TrainConfig(model=cfg, seq_len=shape.seq_len, global_batch=shape.global_batch,
-                     remat=remat, grad_accum=grad_accum,
-                     sync=SyncConfig(mode=sync_mode, compressor=compressor,
-                                     sync_period=sync_period))
-
-    # abstract state
-    params_abs = jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0))
-    state_abs = jax.eval_shape(
-        lambda: init_train_state(jax.random.PRNGKey(0),
-                                 jax.tree_util.tree_map(
-                                     lambda s: jnp.zeros(s.shape, s.dtype), params_abs),
-                                 tc, n_groups, n_pods))
-
-    # shardings
-    mode = sync_mode
-    dax = daxes if len(daxes) > 1 else daxes[0]
-    from repro.models.transformer import set_activation_sharding
-    if mode in ("hier", "local"):
-        rep_ax = ("pod",) if mode == "hier" else dax
-        fsdp = ("data",) if mode == "hier" else None
-        pspecs = param_specs(state_abs.params, mesh, extra_leading=2,
-                             replica_axes=rep_ax if not isinstance(rep_ax, tuple) or len(rep_ax) > 1 else rep_ax[0],
-                             fsdp_axes=fsdp)
-        set_activation_sharding(
-            NamedSharding(mesh, P("data", None, "model")) if mode == "hier"
-            else NamedSharding(mesh, P(None, None, "model")))
-    elif mode == "dense":
-        from repro.sharding import rules as _rules
-        fsdp_ax = daxes + ("model",) if _rules.NO_TP else daxes
-        pspecs = param_specs(state_abs.params, mesh, extra_leading=1, fsdp_axes=fsdp_ax)
-        if _rules.NO_TP:
-            # pure data parallel: batch over ALL axes, no model-dim sharding
-            set_activation_sharding(NamedSharding(mesh, P(daxes + ("model",), None, None)))
-        else:
-            set_activation_sharding(NamedSharding(mesh, P(dax, None, "model")))
-    else:  # efbv family: per-group grads via vmap — batch dim is mapped
-        pspecs = param_specs(state_abs.params, mesh, extra_leading=1, fsdp_axes=daxes)
-        set_activation_sharding(NamedSharding(mesh, P(None, None, "model")))
-    ospecs_mu = jax.tree_util.tree_map(lambda p, s: P(*s), state_abs.opt_state.mu, pspecs)
-    opt_specs = type(state_abs.opt_state)(step=P(), mu=ospecs_mu, nu=ospecs_mu)
-    if state_abs.sync_state is None:
-        sync_specs = None
-    else:
-        if mode in ("efbv", "ef21", "diana"):
-            # h_i per worker group: leading dim over (pod, data); param dims
-            # keep tensor-parallel sharding only (no fsdp — the group axis
-            # already consumes the data axes)
-            h_base = param_specs(state_abs.params, mesh, extra_leading=1)
-            h_specs = jax.tree_util.tree_map(lambda s: P(dax, *tuple(s)), h_base)
-            hb_specs = jax.tree_util.tree_map(lambda s: P(*s), pspecs)
-        else:
-            h_specs = ()
-            # h_bar: no replica dim — param spec minus the leading replica axis
-            hb_specs = jax.tree_util.tree_map(lambda s: P(*tuple(s)[1:]), pspecs)
-        sync_specs = type(state_abs.sync_state)(h=h_specs, h_bar=hb_specs, step=P())
-    state_specs = type(state_abs)(params=pspecs, opt_state=opt_specs,
-                                  sync_state=sync_specs, key=P())
-
-    batch_abs = input_specs(cfg, shape)
-    bspecs = batch_specs(batch_abs, mesh)
-
-    # pin gradient sharding to the param sharding so FSDP backward grads are
-    # reduce-scattered instead of kept replicated through the f32 update
-    from repro.sharding.context import set_grad_specs, set_moe_specs
-    if mode == "dense":
-        set_grad_specs(_sharding(mesh, pspecs))
-    else:
-        set_grad_specs(None)
-    if cfg.moe is not None:
-        # shard_map expert parallelism for train/prefill (scatter dispatch is
-        # unpartitionable); efbv's vmap-over-groups keeps the scatter path
-        impl = "shardmap" if mode in ("dense", "hier") else "scatter"
-        from repro.sharding.context import get_moe_gather_quant, get_moe_impl_override
-        impl = get_moe_impl_override() or impl
-        set_moe_specs({"impl": impl, "mesh": mesh, "data_axes": daxes,
-                       "gather_quant": get_moe_gather_quant(),
-                       "tokens": P(None, "model"),
-                       "expanded": P(None, "model"),
-                       "buf": P("model", None, None)})
-    else:
-        set_moe_specs(None)
-
-    step_fn = make_train_step(cfg, tc, n_groups, n_pods)
-    jitted = jax.jit(
-        step_fn,
-        in_shardings=(_sharding(mesh, state_specs), _sharding(mesh, bspecs)),
-        out_shardings=(_sharding(mesh, state_specs),
-                       _sharding(mesh, jax.tree_util.tree_map(lambda _: P(), {"loss": 0, "ce": 0, "grad_norm": 0}))),
-    )
-    with mesh:
-        lowered = jitted.lower(state_abs, batch_abs)
-    return lowered
-
-
-def _serving_fsdp(cfg, mesh):
-    """FSDP params for serving only when tensor-parallel-only weights would
-    not fit HBM (weight-gathered inference for the >60B archs)."""
-    tp_bytes = cfg.param_count() * 2 / mesh.shape["model"]
-    return data_axes(mesh) if tp_bytes > 8e9 else None
-
-
-def build_prefill_lowering(cfg, mesh, shape, remat="full"):
-    from repro.models.transformer import set_activation_sharding
-    from repro.sharding.context import set_moe_specs
-    daxes = data_axes(mesh)
-    dax = daxes if len(daxes) > 1 else daxes[0]
-    set_activation_sharding(NamedSharding(mesh, P(dax, None, "model")))
-    if cfg.moe is not None:
-        from repro.sharding.context import get_moe_gather_quant, get_moe_impl_override
-        set_moe_specs({"impl": get_moe_impl_override() or "shardmap",
-                       "mesh": mesh, "data_axes": daxes,
-                       "gather_quant": get_moe_gather_quant()})
-    else:
-        set_moe_specs(None)
-    params_abs = jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0))
-    pspecs = param_specs(params_abs, mesh, extra_leading=1,
-                         fsdp_axes=_serving_fsdp(cfg, mesh))
-    batch_abs = input_specs(cfg, shape)
-    bspecs = batch_specs(batch_abs, mesh)
-    from repro.sharding.rules import maybe_axis
-    logits_spec = P(maybe_axis(shape.global_batch, dax, mesh), None,
-                    maybe_axis(cfg.padded_vocab(), "model", mesh))
-    cache_abs = jax.eval_shape(
-        lambda p, b: make_prefill_step(cfg, remat)(p, b)[1], params_abs, batch_abs)
-    cspecs = cache_pspecs(cache_abs, mesh)
-
-    jitted = jax.jit(
-        make_prefill_step(cfg, remat),
-        in_shardings=(_sharding(mesh, pspecs), _sharding(mesh, bspecs)),
-        out_shardings=(NamedSharding(mesh, logits_spec), _sharding(mesh, cspecs)),
-    )
-    with mesh:
-        return jitted.lower(params_abs, batch_abs)
-
-
-def build_decode_lowering(cfg, mesh, shape):
-    from repro.models.transformer import set_activation_sharding
-    from repro.sharding.context import set_moe_specs
-    set_activation_sharding(None)
-    set_moe_specs(None)  # decode keeps the scatter dispatch (tiny T)
-    params_abs = jax.eval_shape(lambda k: init_params(k, cfg), jax.random.PRNGKey(0))
-    pspecs = param_specs(params_abs, mesh, extra_leading=1,
-                         fsdp_axes=_serving_fsdp(cfg, mesh))
-    specs = input_specs(cfg, shape)
-    token_abs, cache_abs = specs["token"], specs["cache"]
-    tspec = batch_specs({"t": token_abs}, mesh)["t"]
-    cspecs = cache_pspecs(cache_abs, mesh)
-    from repro.sharding.rules import maybe_axis
-    logits_spec = P(tuple(tspec)[0], None, maybe_axis(cfg.padded_vocab(), "model", mesh))
-
-    jitted = jax.jit(
-        make_decode_step(cfg),
-        in_shardings=(_sharding(mesh, pspecs), NamedSharding(mesh, tspec),
-                      _sharding(mesh, cspecs)),
-        out_shardings=(NamedSharding(mesh, logits_spec), _sharding(mesh, cspecs)),
-    )
-    with mesh:
-        return jitted.lower(params_abs, token_abs, cache_abs)
 
 
 def run_one(arch: str, shape_name: str, multi_pod: bool, sync_mode: str = "dense",
@@ -309,10 +104,12 @@ def main():
                     log.info("  error: %s", rec["error"])
     ok = sum(r["status"] == "ok" for r in results)
     sk = sum(r["status"] == "skipped" for r in results)
-    log.info("done: %d ok, %d skipped, %d error of %d", ok, sk,
-             len(results) - ok - sk, len(results))
+    n_err = len(results) - ok - sk
+    log.info("done: %d ok, %d skipped, %d error of %d", ok, sk, n_err,
+             len(results))
     return results
 
 
 if __name__ == "__main__":
-    main()
+    # every record is written; the exit code says whether any failed
+    sys.exit(1 if any(r["status"] == "error" for r in main()) else 0)

@@ -85,7 +85,7 @@ def reset_variants():
 def measure(arch, shape_name, variants, multi_pod=False):
     import jax
     from repro.configs.base import INPUT_SHAPES, get_config
-    from repro.launch import dryrun as dr
+    from repro.launch import sharded as dr
     from repro.launch.costing import corrected_costs, model_flops
     from repro.launch.mesh import make_production_mesh
     from repro.launch import hlo_analysis as hlo

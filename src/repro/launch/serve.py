@@ -33,7 +33,9 @@ def main():
     import numpy as np
     from repro.configs import get_config
     from repro.models import decode_step, init_params, prefill
+    from repro.utils.compile_cache import use_compile_cache
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

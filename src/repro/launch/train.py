@@ -1,9 +1,12 @@
-"""Production training launcher.
+"""Training launcher for one host.
 
-On a real TPU slice this is the entry each host runs (jax.distributed
-initializes from the TPU environment); on this CPU container it runs the same
-code over a host mesh, or — with ``--dry-run`` — delegates to the multi-pod
-dry-run for the requested arch/shape/sync.
+Trains through ``training.loop.train`` on the devices of this process: on a
+host with several devices, over a mesh of all N of them with the sharded
+step of launch/sharded.py, (data=N, model=1) or, for ``hier``, (pod=N): the
+sync modes' worker groups are the N shards.  With one device, unsharded.
+It does not call ``jax.distributed.initialize``, so it never spans hosts.
+With ``--dry-run`` it hands over to the multi-pod dry run for the requested
+arch/shape/sync.
 
   PYTHONPATH=src python -m repro.launch.train --arch qwen1.5-4b --dry-run
   PYTHONPATH=src python -m repro.launch.train --arch h2o-danube-1.8b \
@@ -41,10 +44,15 @@ def main():
             "--sync", args.sync, "--compressor", args.compressor,
         ])
 
+    import jax
     from repro.configs import get_config
     from repro.configs.base import SyncConfig, TrainConfig
     from repro.data.synthetic import SyntheticLMDataset, lm_batch_iterator
+    from repro.launch.mesh import make_host_mesh, make_mesh
     from repro.training.loop import train
+    from repro.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -54,9 +62,14 @@ def main():
                      sync=SyncConfig(mode=args.sync, compressor=args.compressor))
     ds = SyntheticLMDataset(vocab_size=cfg.vocab_size, length=100000, seed=0)
     it = lm_batch_iterator(ds, args.batch, args.seq, seed=1)
+    mesh = None
+    if jax.device_count() > 1:
+        # hier keeps one model replica per pod: its replicas need a pod axis
+        mesh = (make_mesh((jax.device_count(), 1, 1), ("pod", "data", "model"))
+                if args.sync == "hier" else make_host_mesh())
     n_groups = 2 if args.sync != "dense" else 1
     train(cfg, tc, it, n_groups=n_groups, n_pods=2, steps=args.steps,
-          ckpt_path=args.ckpt)
+          ckpt_path=args.ckpt, mesh=mesh)
 
 
 if __name__ == "__main__":

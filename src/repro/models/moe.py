@@ -138,7 +138,6 @@ def moe_ffn_alltoall(params: dict, x: jax.Array, *, num_experts: int, top_k: int
                      shared_expert: bool, mesh, data_axes,
                      model_axis: str = "model") -> Tuple[jax.Array, jax.Array]:
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     B, S, d = x.shape
     E, K = num_experts, top_k
@@ -215,12 +214,12 @@ def moe_ffn_alltoall(params: dict, x: jax.Array, *, num_experts: int, top_k: int
             combined = combined.at[token_of].add(contrib.astype(jnp.float32) * wk)
         return combined.astype(xt_sh.dtype), aux
 
-    local = shard_map(
+    local = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(dspec, model_axis), P(), P(model_axis, None, None),
                   P(model_axis, None, None), P(model_axis, None, None)),
         out_specs=(P(dspec, model_axis), P()),
-        check_rep=False,
+        check_vma=False,
     )
     xt = x.reshape(B * S, d)
     w_gate = params.get("w_gate", params["w_in"])
@@ -252,7 +251,6 @@ def moe_ffn_shardmap(params: dict, x: jax.Array, *, num_experts: int, top_k: int
     'model' moves int8 payloads (blockwise absmax, one scale per token) and
     the exit psum runs in bf16 — ~2x less MoE collective traffic."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     B, S, d = x.shape
     E, K = num_experts, top_k
@@ -337,12 +335,12 @@ def moe_ffn_shardmap(params: dict, x: jax.Array, *, num_experts: int, top_k: int
         return combined.astype(x.dtype), aux
 
     in_tok_spec = P(dspec, model_axis) if gather_quant else P(dspec, None)
-    local = shard_map(
+    local = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(in_tok_spec, P(), P(model_axis, None, None),
                   P(model_axis, None, None), P(model_axis, None, None)),
         out_specs=(P(dspec, None), P()),
-        check_rep=False,
+        check_vma=False,
     )
     xt = x.reshape(B * S, d)
     w_gate = params.get("w_gate", params["w_in"])  # placeholder when ungated

@@ -51,15 +51,39 @@ def _fault_model(tc: TrainConfig, n_groups: int, n_pods: int):
 
 def train(cfg: ModelConfig, tc: TrainConfig, batches: Iterator[dict],
           n_groups: int = 1, n_pods: int = 1, steps: Optional[int] = None,
-          ckpt_path: Optional[str] = None, log_every: int = 10):
-    """Single-host training entry (examples / e2e driver).  The multi-pod
-    launcher (launch/train.py) wraps the same step builders under a mesh."""
+          ckpt_path: Optional[str] = None, log_every: int = 10, mesh=None):
+    """Training entry of the launcher (launch/train.py) and the examples.
+
+    Without ``mesh`` the step runs on the default device, with ``n_groups``
+    worker groups vmapped inside it.  With ``mesh`` the step is the sharded
+    one from launch/sharded.py, its worker groups are the mesh's data axes,
+    and the state lives sharded over the mesh.  The state is donated to each
+    step: only the returned state is valid afterwards."""
     steps = steps or tc.total_steps
-    key = jax.random.PRNGKey(tc.seed)
-    key, kinit = jax.random.split(key)
-    params = init_params(kinit, cfg)
-    state = init_train_state(key, params, tc, n_groups, n_pods)
-    step_fn = jax.jit(make_train_step(cfg, tc, n_groups, n_pods))
+    fault_model = _fault_model(tc, n_groups, n_pods)
+    state_shardings = batch_shardings = None
+    if mesh is None:
+        step_fn = jax.jit(make_train_step(cfg, tc, n_groups, n_pods),
+                          donate_argnums=0)
+    else:
+        if fault_model is not None:
+            raise ValueError("fault injection runs on the single-device step; "
+                             "train without a mesh")
+        from repro.launch.sharded import lower_train_step
+
+        sharded = lower_train_step(cfg, mesh, tc)
+        n_groups, n_pods = sharded.n_groups, sharded.n_pods
+        state_shardings = sharded.state_shardings
+        batch_shardings = sharded.batch_shardings
+        step_fn = sharded.lowered.compile()
+
+    def _init(key):
+        key, kinit = jax.random.split(key)
+        return init_train_state(key, init_params(kinit, cfg), tc, n_groups,
+                                n_pods)
+
+    state = jax.jit(_init, out_shardings=state_shardings)(
+        jax.random.PRNGKey(tc.seed))
 
     if tc.sync.mode != "dense":
         from repro.core.distributed import round_comm
@@ -87,7 +111,6 @@ def train(cfg: ModelConfig, tc: TrainConfig, batches: Iterator[dict],
 
             registry.observe_round_cost(0, cost)
 
-    fault_model = _fault_model(tc, n_groups, n_pods)
     fault_nbytes = None
     if fault_model is not None:
         log.info("fault injection on (seed=%d): degraded rounds aggregate "
@@ -117,6 +140,8 @@ def train(cfg: ModelConfig, tc: TrainConfig, batches: Iterator[dict],
             for k, v in batch.items():
                 if k != "tokens":
                     model_batch[k] = jnp.asarray(v)
+            if batch_shardings is not None:
+                model_batch = jax.device_put(model_batch, batch_shardings)
             if fault_model is None:
                 state, metrics = step_fn(state, model_batch)
             else:

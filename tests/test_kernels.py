@@ -1,4 +1,5 @@
-"""Per-kernel shape/dtype sweeps against the pure-jnp oracles (interpret=True)."""
+"""Per-kernel shape/dtype sweeps against the pure-jnp oracles (interpreted
+on the CPU), and the backend helper that picks the kernel mode."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,6 +11,26 @@ except ImportError:  # tier-1 container lacks hypothesis: deterministic shim
 
 from repro.kernels import nm_prune as nmk
 from repro.kernels import ops, quant8, ref
+from repro.kernels.backend import interpret_for, resolve
+
+
+# ---------------------------------------------------------------------------
+# backend helper: one decision for every kernel
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False)])
+def test_interpret_for_known_backends(backend, interpret):
+    assert interpret_for(backend) is interpret
+
+
+@pytest.mark.parametrize("backend", ["gpu", "cuda", "rocm", "metal"])
+def test_interpret_for_unknown_backend_raises(backend):
+    with pytest.raises(RuntimeError, match=backend):
+        interpret_for(backend)
+
+
+def test_resolve_default_follows_backend_and_explicit_wins():
+    assert resolve(None) is interpret_for(jax.default_backend())
+    assert resolve(True) is True and resolve(False) is False
 
 
 # ---------------------------------------------------------------------------

@@ -9,6 +9,7 @@ import subprocess
 import sys
 import textwrap
 
+import numpy as np
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -37,10 +38,11 @@ def test_mini_dryrun_reduced_multipod():
     out = _run("""
         from repro.configs import get_config
         from repro.configs.base import INPUT_SHAPES, InputShape
-        from repro.launch import dryrun as dr
+        from repro.launch import sharded as dr
+        from repro.launch.mesh import make_mesh
         cfg = get_config("jamba-1.5-large-398b").reduced()
         import repro.configs.base as base
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         shape = InputShape("t", 32, 8, "train")
         low = dr.build_train_lowering(cfg, mesh, shape, grad_accum=2)
         comp = low.compile()
@@ -60,9 +62,10 @@ def test_shardmap_moe_matches_scatter():
     """Expert-parallel shard_map MoE == scatter-dispatch MoE numerically."""
     out = _run("""
         from jax.sharding import PartitionSpec as P
+        from repro.launch.mesh import make_mesh
         from repro.models import moe as moe_lib
         from repro.sharding.context import set_moe_specs
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         E, K, d, ff = 4, 2, 64, 128
         params = moe_lib.init_moe(jax.random.PRNGKey(0), d, ff, E, True, False,
                                   jnp.float32)
@@ -70,7 +73,7 @@ def test_shardmap_moe_matches_scatter():
         kw = dict(num_experts=E, top_k=K, capacity_factor=float(E)/K,
                   act="silu", gated=True, shared_expert=False)
         y_ref, aux_ref = moe_lib.moe_ffn(params, x, **kw)
-        with mesh:
+        with jax.set_mesh(mesh):
             y_sm, aux_sm = jax.jit(lambda p, x: moe_lib.moe_ffn_shardmap(
                 p, x, mesh=mesh, data_axes=("data",), **kw))(params, x)
         err = float(jnp.max(jnp.abs(y_ref - y_sm)))
@@ -89,8 +92,9 @@ def test_alltoall_moe_matches_scatter():
     """all-to-all expert dispatch == scatter-dispatch MoE (exact: same
     deterministic routing, same drop-free capacity)."""
     out = _run("""
+        from repro.launch.mesh import make_mesh
         from repro.models import moe as moe_lib
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         E, K, d, ff = 4, 2, 64, 128
         params = moe_lib.init_moe(jax.random.PRNGKey(0), d, ff, E, True, False,
                                   jnp.float32)
@@ -98,7 +102,7 @@ def test_alltoall_moe_matches_scatter():
         kw = dict(num_experts=E, top_k=K, capacity_factor=float(E)/K,
                   act="silu", gated=True, shared_expert=False)
         y_ref, _ = moe_lib.moe_ffn(params, x, **kw)
-        with mesh:
+        with jax.set_mesh(mesh):
             y, _ = jax.jit(lambda p, x: moe_lib.moe_ffn_alltoall(
                 p, x, mesh=mesh, data_axes=("data",), **kw))(params, x)
         out["rel_err"] = float(jnp.linalg.norm(y - y_ref) /
@@ -112,9 +116,10 @@ def test_efbv_sync_mode_lowered_multidev():
     out = _run("""
         from repro.configs import get_config
         from repro.configs.base import InputShape
-        from repro.launch import dryrun as dr
+        from repro.launch import sharded as dr
+        from repro.launch.mesh import make_mesh
         cfg = get_config("qwen1.5-4b").reduced()
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         shape = InputShape("t", 32, 8, "train")
         low = dr.build_train_lowering(cfg, mesh, shape, sync_mode="efbv",
                                       compressor="qsgd")
@@ -122,6 +127,36 @@ def test_efbv_sync_mode_lowered_multidev():
         out["ok"] = True
     """)
     assert out["ok"]
+
+
+@pytest.mark.slow
+def test_sharded_efbv_kernel_train_matches_vmapped():
+    """training.loop.train on a (data=4, model=1) mesh with the Pallas
+    compressor (each device compresses its own group) gives the losses of
+    the one-device step with the 4 groups vmapped; its state spans 4."""
+    out = _run("""
+        from repro.configs import get_config
+        from repro.configs.base import SyncConfig, TrainConfig
+        from repro.data.synthetic import SyntheticLMDataset, lm_batch_iterator
+        from repro.launch.mesh import make_mesh
+        from repro.training.loop import train
+        cfg = get_config("h2o-danube-1.8b").reduced()
+        tc = TrainConfig(model=cfg, seq_len=32, global_batch=4, warmup_steps=1,
+                         sync=SyncConfig(mode="efbv", compressor="qsgd_kernel",
+                                         bucket_size=0))
+        ds = SyntheticLMDataset(vocab_size=cfg.vocab_size, length=5000, seed=0)
+        mesh = make_mesh((4, 1), ("data", "model"), devices=jax.devices()[:4])
+        runs = {}
+        for name, kw in (("mesh", dict(mesh=mesh)), ("vmap", dict(n_groups=4))):
+            state, hist = train(cfg, tc, lm_batch_iterator(ds, 4, 32, seed=1),
+                                steps=3, **kw)
+            runs[name] = [h["loss"] for h in hist]
+            out[name + "_devices"] = sorted({len(a.sharding.device_set) for a
+                                             in jax.tree_util.tree_leaves(state)})
+        out.update(runs)
+    """)
+    assert out["mesh_devices"] == [4] and out["vmap_devices"] == [1], out
+    np.testing.assert_allclose(out["mesh"], out["vmap"], rtol=1e-5)
 
 
 def test_param_specs_rules():

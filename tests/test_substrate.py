@@ -161,3 +161,31 @@ def test_qsgd_sharded_odd_lastdim_fallback():
     y = c(jax.random.PRNGKey(1), x)
     assert y.shape == x.shape
     assert bool(jnp.all(jnp.isfinite(y)))
+
+
+# ---------------------------------------------------------------------------
+# compile cache placement
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("env_dir", [None, "/elsewhere/jax-cache"])
+def test_compile_cache_dir_is_fixed(monkeypatch, env_dir):
+    from repro.utils import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    else:
+        monkeypatch.setenv(compile_cache.ENV_VAR, env_dir)
+    try:
+        where = compile_cache.use_compile_cache()
+        if env_dir is None:
+            # one fixed path inside the checkout, the same on every call
+            assert where == str(compile_cache.DEFAULT_DIR)
+            assert jax.config.jax_compilation_cache_dir == where
+            assert compile_cache.use_compile_cache() == where
+            assert compile_cache.DEFAULT_DIR.name == ".jax_cache"
+        else:
+            # the environment wins and nothing is set in code
+            assert where == env_dir
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
