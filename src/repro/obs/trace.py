@@ -1,18 +1,25 @@
-"""Round-trace flight recorder: spans, ring buffer, JSONL/Chrome exporters.
+"""Round-trace flight recorder: spans, ring buffer, JSONL exporter.
 
 Design constraints (why this looks the way it does):
 
-* **Near-zero cost when disabled.**  ``span()`` checks one module-level flag
-  and returns a single shared no-op context manager — no object allocation,
-  no clock read, no lock.  Tracing is off unless ``enable()`` is called or
-  ``REPRO_TRACE=1`` is set in the environment.
+* **The profiler is the clock.**  While a ``jax.profiler`` session records,
+  every :func:`span` is also a ``jax.profiler.TraceAnnotation``: a host
+  event of the session's trace, on the same clock as the device's events,
+  with its tags as the event's stats.  No switch turns this on: the session
+  does.
+
+* **Near-zero cost when off.**  With the recorder off and no profiler
+  session, ``span()`` checks one module-level flag and the profiler's
+  enabled bit and returns a single shared no-op context manager — no object
+  allocation, no clock read, no lock.  The recorder is off unless
+  ``enable()`` is called or ``REPRO_TRACE=1`` is set in the environment.
 
 * **No host sync inside jit.**  Host-clock spans belong at *dispatch
-  boundaries* (the training loop, codec round boundaries, benchmark
-  harnesses).  Code that runs under ``jax.jit`` uses :func:`annotate`
-  instead — a trace-time ``jax.named_scope`` (optionally doubled with
-  ``jax.profiler.TraceAnnotation``) so the phase names line up with XLA
-  profiles without ever blocking on a device value.
+  boundaries* (the training loop, the serving scheduler, codec round
+  boundaries, benchmark harnesses).  Code that runs under ``jax.jit`` uses
+  :func:`annotate` instead — a trace-time ``jax.named_scope`` that names the
+  phase in the program's op metadata without ever blocking on a device
+  value.
 
 * **Flight recorder.**  Spans land in a fixed-capacity thread-safe ring
   buffer: a long run keeps the most recent window instead of growing without
@@ -27,11 +34,11 @@ Usage::
         payload = encode(...)
         sp.tag(nbytes=payload.nbytes)
 
-    @trace.traced("codec/roundtrip")
-    def roundtrip(x): ...
-
     trace.export_jsonl("TRACE_round.jsonl")
-    trace.export_chrome_trace("TRACE_round.json")   # chrome://tracing
+
+A profiler trace (``jax.profiler.trace(dir, create_perfetto_trace=True)``)
+carries the same spans next to the device's, whether the recorder is on or
+not.
 """
 from __future__ import annotations
 
@@ -41,6 +48,8 @@ import threading
 import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
+
+import jax
 
 _TRUTHY = ("1", "true", "yes", "on")
 
@@ -144,7 +153,6 @@ def wall_s() -> float:
 # ---------------------------------------------------------------------------
 _tracer = Tracer()
 _enabled = os.environ.get("REPRO_TRACE", "").lower() in _TRUTHY
-_jax_annotations = os.environ.get("REPRO_TRACE_JAX", "").lower() in _TRUTHY
 _tls = threading.local()
 
 
@@ -156,15 +164,11 @@ def enabled() -> bool:
     return _enabled
 
 
-def enable(jax_annotations: Optional[bool] = None,
-           capacity: Optional[int] = None) -> None:
-    """Turn the flight recorder on (optionally resizing the ring buffer and
-    opting into ``jax.profiler`` annotations alongside host spans)."""
-    global _enabled, _jax_annotations, _tracer
+def enable(capacity: Optional[int] = None) -> None:
+    """Turn the flight recorder on (optionally resizing the ring buffer)."""
+    global _enabled, _tracer
     if capacity is not None and capacity != _tracer.capacity:
         _tracer = Tracer(capacity)
-    if jax_annotations is not None:
-        _jax_annotations = bool(jax_annotations)
     _enabled = True
 
 
@@ -177,6 +181,11 @@ def set_meta(**kv) -> None:
     """Attach run-level metadata (sync config, n_params, ...) to the trace;
     exported as the JSONL header line so the report CLI can self-configure."""
     _tracer.meta.update(kv)
+
+
+def _profiling() -> bool:
+    """Whether a ``jax.profiler`` session is recording host events now."""
+    return jax.profiler.TraceAnnotation.is_enabled()
 
 
 def _depth_stack() -> list:
@@ -194,7 +203,8 @@ def _ambient_tags() -> Optional[dict]:
 # span context managers
 # ---------------------------------------------------------------------------
 class _NullSpan:
-    """Shared no-op: what ``span()``/``annotate()`` return when disabled."""
+    """Shared no-op: what ``span()`` returns with the recorder off and no
+    profiler session."""
     __slots__ = ()
 
     def __enter__(self):
@@ -210,30 +220,40 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+class _ProfilerSpan(jax.profiler.TraceAnnotation):
+    """A span as a host event of the profiler's trace: tags given at entry
+    and by ``tag()`` become the event's stats."""
+
+    def tag(self, **kv) -> "_ProfilerSpan":
+        self.set_metadata(**kv)
+        return self
+
+
 class _SpanCtx:
-    __slots__ = ("name", "tags", "_t0_ns", "_jax_ctx")
+    """A span of the flight recorder, also a profiler event while a session
+    records."""
+    __slots__ = ("name", "tags", "_t0_ns", "_event")
 
     def __init__(self, name: str, tags: dict):
         self.name = name
         self.tags = tags
         self._t0_ns = 0
-        self._jax_ctx = None
+        self._event = _ProfilerSpan(name, **tags)
 
     def tag(self, **kv) -> "_SpanCtx":
         self.tags.update(kv)
+        self._event.tag(**kv)
         return self
 
     def __enter__(self):
         _depth_stack().append(self.name)
-        if _jax_annotations:
-            self._jax_ctx = _enter_jax_annotation(self.name)
+        self._event.__enter__()
         self._t0_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         t1_ns = time.perf_counter_ns()
-        if self._jax_ctx is not None:
-            self._jax_ctx.__exit__(*exc)
+        self._event.__exit__(*exc)
         stack = _depth_stack()
         depth = len(stack) - 1
         if stack:
@@ -248,34 +268,19 @@ class _SpanCtx:
 
 
 def span(name: str, **tags):
-    """Host-clock span: ``with span("codec/encode", level="inter") as sp:``.
+    """Host span: ``with span("codec/encode", level="inter") as sp:``.
 
-    Disabled mode returns the shared :data:`NULL_SPAN` — no allocation beyond
-    the call itself, no clock read.  ``sp.tag(nbytes=...)`` adds tags that are
-    only known at exit time.
+    Recorded in the ring buffer when the recorder is on, and a host event of
+    the profiler's trace while a session records.  With neither it returns
+    the shared :data:`NULL_SPAN` — no allocation beyond the call itself, no
+    clock read.  ``sp.tag(nbytes=...)`` adds tags that are only known at
+    exit time.
     """
-    if not _enabled:
-        return NULL_SPAN
-    return _SpanCtx(name, tags)
-
-
-def traced(name: Optional[str] = None, **tags):
-    """Decorator flavor of :func:`span` (checks the flag per call)."""
-    def deco(fn):
-        sp_name = name or getattr(fn, "__qualname__", fn.__name__)
-
-        def wrapper(*a, **kw):
-            if not _enabled:
-                return fn(*a, **kw)
-            with _SpanCtx(sp_name, dict(tags)):
-                return fn(*a, **kw)
-
-        wrapper.__name__ = getattr(fn, "__name__", sp_name)
-        wrapper.__qualname__ = getattr(fn, "__qualname__", sp_name)
-        wrapper.__doc__ = fn.__doc__
-        wrapper.__wrapped__ = fn
-        return wrapper
-    return deco
+    if _enabled:
+        return _SpanCtx(name, tags)
+    if _profiling():
+        return _ProfilerSpan(name, **tags)
+    return NULL_SPAN
 
 
 class _AmbientCtx:
@@ -309,41 +314,22 @@ def ambient(**tags):
 # ---------------------------------------------------------------------------
 # jax passthrough (trace-safe: never reads the host clock inside jit)
 # ---------------------------------------------------------------------------
-def _enter_jax_annotation(name: str):
-    try:
-        import jax
-        ctx = jax.profiler.TraceAnnotation(name)
-        ctx.__enter__()
-        return ctx
-    except Exception:  # profiler unavailable (headless CPU builds)
-        return None
-
-
 def annotate(name: str):
     """Phase annotation for code *inside* jit: a ``jax.named_scope`` so the
-    phase shows up in jaxpr/HLO metadata and XLA profiles.  This is the only
-    instrumentation allowed under a jit trace — it costs nothing at runtime
-    (names are baked in at trace time) and never forces a host sync.  Returns
-    the shared no-op when tracing is disabled."""
-    if not _enabled:
-        return NULL_SPAN
-    import jax
-
+    phase shows up in the jaxpr and in each HLO instruction's metadata.  This
+    is the only instrumentation allowed under a jit trace — it acts at trace
+    time, changes op metadata and not the compiled program, and never forces
+    a host sync."""
     return jax.named_scope(name)
 
 
 def step_annotation(step: int, name: str = "train"):
-    """``jax.profiler.StepTraceAnnotation`` passthrough for round boundaries
-    (lines host rounds up with device steps in an XLA profile).  Only active
-    when jax annotations were opted into via ``enable(jax_annotations=True)``
-    or ``REPRO_TRACE_JAX=1``."""
-    if not (_enabled and _jax_annotations):
+    """``jax.profiler.StepTraceAnnotation`` for round boundaries (lines host
+    rounds up with device steps in an XLA profile) while a profiler session
+    records; the shared no-op otherwise."""
+    if not _profiling():
         return NULL_SPAN
-    try:
-        import jax
-        return jax.profiler.StepTraceAnnotation(name, step_num=step)
-    except Exception:
-        return NULL_SPAN
+    return jax.profiler.StepTraceAnnotation(name, step_num=step)
 
 
 # ---------------------------------------------------------------------------
@@ -382,22 +368,3 @@ def load_jsonl(path: str) -> Tuple[dict, List[Span]]:
                 spans.append(Span.from_json(d))
     return meta, spans
 
-
-def export_chrome_trace(path: str, tracer: Optional[Tracer] = None) -> str:
-    """Chrome ``chrome://tracing`` / Perfetto JSON: complete ("ph": "X")
-    events with microsecond timestamps, span tags under ``args``."""
-    tr = tracer or _tracer
-    events = []
-    for s in tr.spans():
-        events.append({
-            "name": s.name, "ph": "X", "cat": "repro",
-            "ts": round(s.ts_us, 3), "dur": round(s.dur_us, 3),
-            "pid": os.getpid(), "tid": s.tid,
-            "args": {k: v for k, v in s.tags.items()},
-        })
-    doc = {"traceEvents": events, "displayTimeUnit": "ms",
-           "otherData": dict(tr.meta)}
-    with open(path, "w") as f:
-        json.dump(doc, f, indent=1)
-        f.write("\n")
-    return path

@@ -128,12 +128,12 @@ def train(cfg: ModelConfig, tc: TrainConfig, batches: Iterator[dict],
     history = []
     t0 = obs_trace.wall_s()
     for step in range(steps):
-        tracing = obs_trace.enabled()
         # round boundary: the span covers batch staging + step dispatch, but
         # never blocks on device values — the blocking fetch is its own span
         with obs_trace.span("round/step", round=step), \
                 obs_trace.step_annotation(step):
-            batch = next(batches)
+            with obs_trace.span("round/next_batch"):
+                batch = next(batches)
             tokens = batch["tokens"]
             model_batch = {"tokens": jnp.asarray(tokens[:, :-1]),
                            "targets": jnp.asarray(tokens[:, 1:])}
@@ -151,31 +151,29 @@ def train(cfg: ModelConfig, tc: TrainConfig, batches: Iterator[dict],
                                               nbytes_by_level=fault_nbytes)
                 masks = tuple(jnp.asarray(m) for m in plan.survivor_masks())
                 state, metrics = step_fn(state, model_batch, masks)
-        if fault_model is not None and tracing:
+        if fault_model is not None and obs_trace.enabled():
             from repro.obs import registry
 
             registry.observe_fault_plan(step, plan)
         # metrics stay on device (async dispatch): one jax.device_get per log
         # point instead of a blocking float(v) transfer per metric per step
         history.append(metrics)
-        log_step = step % log_every == 0 or step == steps - 1
-        if tracing or log_step:
+        if step % log_every == 0 or step == steps - 1:
             with obs_trace.span("round/blocking_fetch", round=step):
                 fetched = jax.device_get(metrics)
-            if tracing:
-                from repro.obs import registry
-
-                vals = {k: float(v) for k, v in fetched.items()}
-                registry.observe_train_step(step, vals)
-                log_kv(log, "round", step=step, **vals)
-            if log_step:
-                dt = obs_trace.wall_s() - t0
-                log.info("step %4d loss %.4f grad_norm %.3f (%.2fs)",
-                         step, float(fetched["loss"]),
-                         float(fetched["grad_norm"]), dt)
+            dt = obs_trace.wall_s() - t0
+            log.info("step %4d loss %.4f grad_norm %.3f (%.2fs)",
+                     step, float(fetched["loss"]),
+                     float(fetched["grad_norm"]), dt)
     # one transfer drains every step's still-on-device metrics
     history = [{k: float(v) for k, v in h.items()}
                for h in jax.device_get(history)]
+    if obs_trace.enabled():
+        from repro.obs import registry
+
+        for step, vals in enumerate(history):
+            registry.observe_train_step(step, vals)
+            log_kv(log, "round", step=step, **vals)
     if ckpt_path:
         save_checkpoint(ckpt_path, state.params, step=steps)
         log.info("saved checkpoint to %s", ckpt_path)

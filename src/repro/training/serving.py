@@ -17,10 +17,24 @@ Subclass hooks (``repro.serve.engine.PersonalizedBatcher`` uses all four):
 ``_build_model`` constructs the jitted steps, ``_model_prefill`` /
 ``_model_decode`` run them, ``_on_admit`` / ``_on_retire`` bracket a
 request's residency in a slot (page-in/pin and release in the personalized
-engine).  Admit/prefill/decode are traced as ``serve/*`` spans when the
-``repro.obs`` flight recorder is on, and ``publish_stats`` bridges
-:class:`ServeStats` into the obs metrics registry so
-``python -m repro.obs.report`` covers the serving path.
+engine).  ``publish_stats`` bridges :class:`ServeStats` into the obs metrics
+registry so ``python -m repro.obs.report`` covers the serving path.
+
+Each ``step()`` is traced as ``repro.obs`` spans, recorded by the flight
+recorder when it is on and written into the profiler's trace while a
+``jax.profiler`` session records::
+
+    serve/step                  the whole tick
+      serve/admit               filling free slots (tags new, live)
+        serve/prefill           dispatch of the prefill (tags rows, tokens)
+        serve/fetch             the host's wait for the prefill's tokens
+      serve/decode              staging the tokens and dispatching the step
+      serve/fetch               the host's wait for the sampled tokens
+      serve/emit                appending tokens, stop tests, retirements
+
+The device idles inside ``serve/fetch`` while the token travels to the host,
+and elsewhere in ``serve/step`` while the scheduler's own Python runs.  The
+programs are named ``jit_prefill`` and ``jit_decode``.
 """
 from __future__ import annotations
 
@@ -72,11 +86,16 @@ class ContinuousBatcher:
 
     # -- model hooks (overridden by delta-serving subclasses) ---------------
     def _build_model(self) -> None:
-        from repro.models import decode_step, prefill
-        self._prefill = jax.jit(
-            lambda p, b: prefill(p, self.cfg, b, cache_len=self.max_len))
-        self._decode = jax.jit(
-            lambda p, t, c: decode_step(p, self.cfg, t, c))
+        from repro import models
+
+        def prefill(p, b):
+            return models.prefill(p, self.cfg, b, cache_len=self.max_len)
+
+        def decode(p, t, c):
+            return models.decode_step(p, self.cfg, t, c)
+
+        self._prefill = jax.jit(prefill)
+        self._decode = jax.jit(decode)
 
     def _model_prefill(self, batch):
         return self._prefill(self.params, batch)
@@ -134,39 +153,46 @@ class ContinuousBatcher:
             if self.cfg.vision_tokens:
                 batch["vision_embeds"] = jnp.zeros(
                     (self.n_slots, self.cfg.vision_tokens, self.cfg.d_model))
-            with obs_trace.span("serve/prefill", tokens=int(maxlen)):
+            with obs_trace.span("serve/prefill", rows=len(live),
+                                tokens=int(maxlen)):
                 logits, self.cache = self._model_prefill(batch)
-            self.next_tok = np.asarray(
-                jnp.argmax(logits[:, -1, :self.cfg.vocab_size],
-                           -1))[:, None].astype(np.int32)
+            first = jnp.argmax(logits[:, -1, :self.cfg.vocab_size], -1)
+            with obs_trace.span("serve/fetch"):
+                first = np.asarray(first)
+            self.next_tok = first[:, None].astype(np.int32)
             self.stats.prefills += 1
 
     # -- decode --------------------------------------------------------------
     def step(self) -> int:
         """One scheduler tick: admit if possible, then one decode step for all
         live slots. Returns the number of live requests."""
-        if self._free_slots() and self.queue:
-            self._admit()
-        live = [i for i, r in enumerate(self.slots)
-                if r is not None and not r.done]
-        if not live or self.cache is None:
-            return 0
-        with obs_trace.span("serve/decode", live=len(live)):
-            logits, self.cache = self._model_decode(jnp.asarray(self.next_tok))
-        nxt = np.asarray(jnp.argmax(logits[:, -1, :self.cfg.vocab_size], -1))
-        self.stats.decode_steps += 1
-        for i in live:
-            r = self.slots[i]
-            tok = int(nxt[i])
-            r.generated.append(tok)
-            self.stats.tokens_out += 1
-            if (r.stop_token is not None and tok == r.stop_token) or \
-                    len(r.generated) >= r.max_new:
-                r.done = True
-                self.stats.completed += 1
-                self._on_retire(i, r)
-        self.next_tok = nxt[:, None].astype(np.int32)
-        return len([i for i in live if not self.slots[i].done])
+        with obs_trace.span("serve/step"):
+            if self._free_slots() and self.queue:
+                self._admit()
+            live = [i for i, r in enumerate(self.slots)
+                    if r is not None and not r.done]
+            if not live or self.cache is None:
+                return 0
+            with obs_trace.span("serve/decode", live=len(live)):
+                logits, self.cache = self._model_decode(
+                    jnp.asarray(self.next_tok))
+            nxt = jnp.argmax(logits[:, -1, :self.cfg.vocab_size], -1)
+            with obs_trace.span("serve/fetch"):
+                nxt = np.asarray(nxt)
+            self.stats.decode_steps += 1
+            with obs_trace.span("serve/emit"):
+                for i in live:
+                    r = self.slots[i]
+                    tok = int(nxt[i])
+                    r.generated.append(tok)
+                    self.stats.tokens_out += 1
+                    if (r.stop_token is not None and tok == r.stop_token) or \
+                            len(r.generated) >= r.max_new:
+                        r.done = True
+                        self.stats.completed += 1
+                        self._on_retire(i, r)
+            self.next_tok = nxt[:, None].astype(np.int32)
+            return len([i for i in live if not self.slots[i].done])
 
     def run(self, max_ticks: int = 1000) -> ServeStats:
         for _ in range(max_ticks):

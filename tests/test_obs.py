@@ -52,13 +52,9 @@ def test_span_nesting_and_ordering():
 
 def test_traced_decorator_and_ambient_tags():
     obs_trace.enable()
-
-    @obs_trace.traced("work/fn", kind="unit")
-    def fn(x):
-        return x + 1
-
     with obs_trace.ambient(level="dcn"):
-        assert fn(1) == 2
+        with obs_trace.span("work/fn", kind="unit"):
+            pass
     (s,) = obs_trace.get_tracer().spans()
     assert s.name == "work/fn"
     assert s.tags["kind"] == "unit" and s.tags["level"] == "dcn"
@@ -100,23 +96,64 @@ def test_export_jsonl_roundtrip(tmp_path):
     assert s.name == "phase/x" and s.tags == {"nbytes": 10}
 
 
-def test_chrome_trace_schema(tmp_path):
-    obs_trace.enable()
-    with obs_trace.span("a", level="intra"):
-        with obs_trace.span("b"):
+def _host_events(log_dir) -> dict:
+    """``{line name: [(name, start_ns, end_ns, stats)]}`` of the host plane
+    of the profiler trace written under ``log_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                        recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                out[line.name] = [(e.name, e.start_ns,
+                                   e.start_ns + e.duration_ns, dict(e.stats))
+                                  for e in line.events]
+    return out
+
+
+def _profiler_options():
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    return opts
+
+
+def test_span_reaches_profiler_with_recorder_off(tmp_path):
+    import jax
+
+    assert not obs_trace.enabled()
+    with jax.profiler.trace(str(tmp_path), profiler_options=_profiler_options()):
+        with obs_trace.span("serve/fetch", live=3) as sp:
+            assert sp is not obs_trace.NULL_SPAN
+            sp.tag(rows=5)
+        with obs_trace.step_annotation(7):
             pass
-    path = obs_trace.export_chrome_trace(str(tmp_path / "t.json"))
-    with open(path) as f:
-        doc = json.load(f)
-    assert isinstance(doc["traceEvents"], list) and len(doc["traceEvents"]) == 2
-    for ev in doc["traceEvents"]:
-        assert ev["ph"] == "X"  # complete events
-        assert isinstance(ev["name"], str)
-        assert isinstance(ev["ts"], (int, float))
-        assert isinstance(ev["dur"], (int, float)) and ev["dur"] >= 0
-        assert "pid" in ev and "tid" in ev
-    by_name = {ev["name"]: ev for ev in doc["traceEvents"]}
-    assert by_name["a"]["args"] == {"level": "intra"}
+    assert obs_trace.span("serve/fetch") is obs_trace.NULL_SPAN
+    assert obs_trace.get_tracer().n_recorded == 0
+    lines = _host_events(tmp_path)
+    (main,) = [name for name in lines if name.startswith("python")]
+    spans = {name: stats for name, _, _, stats in lines[main]}
+    assert spans["serve/fetch"] == {"live": 3, "rows": 5}
+    assert spans["train"]["step_num"] == 7
+
+
+def test_annotate_names_ops_with_recorder_off():
+    import jax
+    import jax.numpy as jnp
+
+    assert not obs_trace.enabled()
+
+    def f(x):
+        with obs_trace.annotate("step/grad"):
+            return jnp.sin(x) * 2
+
+    text = jax.jit(f).lower(jnp.ones(4)).as_text(debug_info=True)
+    assert "step/grad" in text
 
 
 # ---------------------------------------------------------------------------
@@ -259,3 +296,27 @@ def test_train_loop_traced_smoke():
     assert names.count("round/blocking_fetch") == 2
     loss = obs_metrics.registry.gauge("train/loss")
     assert len(loss.series) == 2
+
+
+def test_train_fetches_metrics_at_log_steps_only():
+    from repro.configs import get_config
+    from repro.configs.base import TrainConfig
+    from repro.data.synthetic import SyntheticLMDataset, lm_batch_iterator
+    from repro.training.loop import train
+
+    cfg = get_config("h2o-danube-1.8b").reduced()
+    tc = TrainConfig(model=cfg, seq_len=32, global_batch=4, lr=1e-3,
+                     warmup_steps=1, total_steps=3)
+    ds = SyntheticLMDataset(vocab_size=cfg.vocab_size, length=2000, seed=0)
+
+    obs_trace.enable()
+    _, history = train(cfg, tc, lm_batch_iterator(ds, 4, 32, seed=1),
+                       steps=3, log_every=10)
+    assert len(history) == 3
+    spans = obs_trace.get_tracer().spans()
+    fetches = [s.tags["round"] for s in spans
+               if s.name == "round/blocking_fetch"]
+    assert fetches == [0, 2]
+    assert [s.name for s in spans].count("round/next_batch") == 3
+    loss = obs_metrics.registry.gauge("train/loss")
+    assert len(loss.series) == 3

@@ -153,3 +153,61 @@ def test_serve_stats_metrics_bridge(served):
     assert stats["completed"] == 1.0
     assert stats["tokens_out"] == 3.0
     assert stats["decode_steps"] == cb.stats.decode_steps
+
+
+def _profiled_spans(log_dir) -> list:
+    """``[(name, start_ns, end_ns)]`` of the program's ``serve/*`` spans in
+    the profiler trace written under ``log_dir``."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                        recursive=True)
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events
+            if e.name.startswith("serve/")]
+
+
+def test_step_spans_under_profiler(served, tmp_path):
+    """Under a profiler session, with the flight recorder off, every tick
+    writes its spans into the trace: one ``serve/decode`` per decode step,
+    and every fetch and emit inside a ``serve/step``."""
+    cfg, params = served
+    cb = ContinuousBatcher(cfg, params, n_slots=2, max_len=64)
+    for rid in range(3):           # a third request forces a refill
+        cb.submit(Request(rid=rid, prompt=np.array([4, 5, 6], np.int32),
+                          max_new=3))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        cb.run(max_ticks=50)
+    spans = _profiled_spans(tmp_path)
+    names = [n for n, _, _ in spans]
+    assert names.count("serve/decode") == cb.stats.decode_steps
+    assert names.count("serve/prefill") == cb.stats.prefills == 2
+    assert names.count("serve/fetch") == cb.stats.decode_steps + 2
+    steps = [(a, b) for n, a, b in spans if n == "serve/step"]
+    for n, a, b in spans:
+        if n in ("serve/fetch", "serve/emit"):
+            assert any(s <= a and b <= e for s, e in steps), (n, a, b)
+
+
+def test_programs_are_named(served):
+    """The two step programs read ``jit_prefill`` and ``jit_decode`` in a
+    profile, not ``jit__lambda``."""
+    import jax.numpy as jnp
+
+    cfg, params = served
+    cb = ContinuousBatcher(cfg, params, n_slots=2, max_len=64)
+    cb.submit(Request(rid=0, prompt=np.array([4, 5, 6], np.int32), max_new=2))
+    cb.step()
+    prefill = cb._prefill.lower(
+        params, {"tokens": jnp.zeros((2, 3), jnp.int32)}).as_text()
+    decode = cb._decode.lower(params, jnp.asarray(cb.next_tok),
+                              cb.cache).as_text()
+    assert "module @jit_prefill" in prefill
+    assert "module @jit_decode" in decode
