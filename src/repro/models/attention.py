@@ -207,7 +207,9 @@ def attention_prefill(params, x, *, cfg_attn: dict):
 
 
 def cache_spec(cfg_attn: dict, batch: int, seq_len: int, dtype):
-    """Decode-cache shapes for one attention layer.
+    """Decode-cache shapes for one attention layer, sequence-major:
+    (S, B, KV, hd), so that writing one position touches whole (B, hd)
+    tiles and needs no relayout of the rest.
 
     SWA / chunked layers bound the live context, so the cache is the window
     (this is exactly why those archs qualify for long_500k)."""
@@ -220,15 +222,20 @@ def cache_spec(cfg_attn: dict, batch: int, seq_len: int, dtype):
         S = seq_len
     kv, hd = cfg_attn["num_kv_heads"], cfg_attn["head_dim"]
     return {
-        "k": jax.ShapeDtypeStruct((batch, S, kv, hd), dtype),
-        "v": jax.ShapeDtypeStruct((batch, S, kv, hd), dtype),
+        "k": jax.ShapeDtypeStruct((S, batch, kv, hd), dtype),
+        "v": jax.ShapeDtypeStruct((S, batch, kv, hd), dtype),
     }
 
 
-def attention_decode(params, x, cache: dict, pos: jax.Array, *, cfg_attn: dict):
-    """One-token decode. x (B,1,D); cache{k,v} (B,Sc,KV,hd); pos () int32 —
-    number of tokens already in context.  Ring-buffer write for windowed
-    layers; returns (out, new_cache)."""
+def attention_decode(params, x, cache: dict, pos: jax.Array, layer, *,
+                     cfg_attn: dict):
+    """One-token decode of one layer. x (B,1,D); cache{k,v} the caches of
+    every layer stacked, (L,Sc,B,KV,hd); layer () int32 — which of them;
+    pos () int32 — number of tokens already in context.
+
+    The token's K/V is written into the stack in place at (layer, slot),
+    a ring slot for windowed layers, and the layer's K/V is read where it
+    is stored; returns (out, updated stack)."""
     B = x.shape[0]
     H, KV, hd = cfg_attn["num_heads"], cfg_attn["num_kv_heads"], cfg_attn["head_dim"]
     positions = jnp.full((B, 1), pos, jnp.int32)
@@ -236,8 +243,14 @@ def attention_decode(params, x, cache: dict, pos: jax.Array, *, cfg_attn: dict):
                                    cfg_attn["use_rope"], positions, cfg_attn["rope_theta"])
     Sc = cache["k"].shape[1]
     slot = jnp.mod(pos, Sc)  # ring for windowed layers; == pos when Sc==seq_len
-    k = jax.lax.dynamic_update_slice(cache["k"], k_new, (0, slot, 0, 0))
-    v = jax.lax.dynamic_update_slice(cache["v"], v_new, (0, slot, 0, 0))
+
+    def write(stack, new):  # new (B,1,KV,hd) -> (1,1,B,KV,hd) at (layer, slot)
+        stack = jax.lax.dynamic_update_slice(stack, new.swapaxes(0, 1)[None],
+                                             (layer, slot, 0, 0, 0))
+        return stack, jax.lax.dynamic_index_in_dim(stack, layer, 0, keepdims=False)
+
+    k_all, k = write(cache["k"], k_new)
+    v_all, v = write(cache["v"], v_new)
 
     # live-slot mask: slot index valid if it holds one of the last `live` tokens
     kind = cfg_attn["kind"]
@@ -257,9 +270,9 @@ def attention_decode(params, x, cache: dict, pos: jax.Array, *, cfg_attn: dict):
 
     scale = 1.0 / math.sqrt(hd)
     qf = (q.astype(jnp.float32) * scale).reshape(B, 1, KV, H // KV, hd)
-    s = jnp.einsum("bqkgh,bnkh->bqkgn", qf, k.astype(jnp.float32))
+    s = jnp.einsum("bqkgh,nbkh->bqkgn", qf, k.astype(jnp.float32))
     s = s + bias[None, None, None, None, :]
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bqkgn,bnkh->bqkgh", p, v.astype(jnp.float32))
+    out = jnp.einsum("bqkgn,nbkh->bqkgh", p, v.astype(jnp.float32))
     out = out.reshape(B, 1, H * hd).astype(x.dtype) @ params["wo"]
-    return out, {"k": k, "v": v}
+    return out, {"k": k_all, "v": v_all}

@@ -345,14 +345,22 @@ def cache_specs(cfg: ModelConfig, batch: int, seq_len: int, enc_len: int = 0):
     return out
 
 
-def _apply_block_decode(bp, cfg: ModelConfig, kind: str, use_moe: bool, x, lcache,
-                        pos, enc_memory):
+def _apply_block_decode(bp, cfg: ModelConfig, kind: str, use_moe: bool, x, caches,
+                        layer, pos, enc_memory):
+    """One block of one decode step. ``caches`` holds this block position's
+    caches of every period stacked; the block reads and writes its own,
+    ``layer``, in place. Returns (x, caches)."""
     h = rmsnorm(bp["norm1"], x, cfg.norm_eps)
     if kind == MAMBA:
-        h, new_cache = mamba_lib.mamba_decode(bp["mamba"], h, lcache, cfg.mamba, cfg.d_model)
+        lcache = jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False), caches)
+        h, new = mamba_lib.mamba_decode(bp["mamba"], h, lcache, cfg.mamba, cfg.d_model)
+        caches = jax.tree_util.tree_map(
+            lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, layer, 0),
+            caches, new)
     else:
-        h, new_cache = attn_lib.attention_decode(
-            bp["attn"], h, lcache, pos, cfg_attn=_attn_cfg(cfg, kind))
+        h, caches = attn_lib.attention_decode(
+            bp["attn"], h, caches, pos, layer, cfg_attn=_attn_cfg(cfg, kind))
     x = x + h
     if cfg.cross_attn and enc_memory is not None:
         h = rmsnorm(bp["norm_x"], x, cfg.norm_eps)
@@ -368,27 +376,33 @@ def _apply_block_decode(bp, cfg: ModelConfig, kind: str, use_moe: bool, x, lcach
         else:
             h = mlp(bp["mlp"], h, act=cfg.mlp_act, gated=cfg.mlp_gated)
         x = x + h
-    return x, new_cache
+    return x, caches
 
 
 def decode_step(params, cfg: ModelConfig, token: jax.Array, cache: dict):
-    """token (B, 1) int32; cache from cache_specs/prefill. Returns (logits, cache)."""
+    """token (B, 1) int32; cache from cache_specs/prefill. Returns (logits, cache).
+
+    The stacked layer caches ride in the layer scan's carry, so each layer
+    writes its token into them in place rather than the scan slicing every
+    layer's cache out and stacking a new one."""
     P, n_periods, pos_kinds, pos_moe = period_info(cfg)
     pos = cache["pos"]
     x = embed(params["embed"], token)
     enc_memory = cache.get("enc_memory")
 
-    def period_body(x, scanned):
-        bps, lcaches = scanned
-        new_caches = {}
+    def period_body(carry, scanned):
+        x, caches = carry
+        bps, layer = scanned
+        caches = dict(caches)
         for j in range(P):
-            x, nc = _apply_block_decode(
-                bps[f"pos{j}"], cfg, pos_kinds[j], pos_moe[j], x, lcaches[f"pos{j}"],
-                pos, enc_memory)
-            new_caches[f"pos{j}"] = nc
-        return x, new_caches
+            x, caches[f"pos{j}"] = _apply_block_decode(
+                bps[f"pos{j}"], cfg, pos_kinds[j], pos_moe[j], x, caches[f"pos{j}"],
+                layer, pos, enc_memory)
+        return (x, caches), None
 
-    x, new_layer_caches = stack_scan(period_body, x, (params["blocks"], cache["layers"]))
+    (x, new_layer_caches), _ = stack_scan(
+        period_body, (x, cache["layers"]),
+        (params["blocks"], jnp.arange(n_periods, dtype=jnp.int32)))
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = unembed(params["embed"], x)
     new_cache = dict(cache)
@@ -398,7 +412,8 @@ def decode_step(params, cfg: ModelConfig, token: jax.Array, cache: dict):
 
 
 def _ring_from_prefill(kv: dict, cfg_attn: dict, S: int, cache_len: int):
-    """Convert full prefill K/V (B,S,KV,hd) into the decode cache.
+    """Convert full prefill K/V (B,S,KV,hd) into the sequence-major decode
+    cache (Sc,B,KV,hd).
 
     Windowed kinds get a ring of the last `Sc` live positions placed so that
     slot == pos % Sc; the global kind gets a slot==pos cache padded out to
@@ -410,19 +425,15 @@ def _ring_from_prefill(kv: dict, cfg_attn: dict, S: int, cache_len: int):
     elif kind == "attn_chunk":
         Sc = min(cache_len, cfg_attn["chunk"])
     else:
-        pad = cache_len - S
-        if pad <= 0:
-            return kv
-        padded = lambda a: jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        return {"k": padded(kv["k"]), "v": padded(kv["v"])}
+        Sc = max(cache_len, S)
 
     def ring(a):
+        a = a.swapaxes(0, 1)  # (S, B, KV, hd)
         if S < Sc:
-            a = jnp.pad(a, ((0, 0), (0, Sc - S), (0, 0), (0, 0)))
-            return a  # slot == pos, not yet wrapped
-        tail = a[:, S - Sc:, ...]
+            return jnp.pad(a, ((0, Sc - S), (0, 0), (0, 0), (0, 0)))  # slot == pos
+        tail = a[S - Sc:]
         # element j holds pos S-Sc+j whose slot is (S-Sc+j) % Sc == (j + S) % Sc
-        return jnp.roll(tail, shift=S % Sc, axis=1)
+        return jnp.roll(tail, shift=S % Sc, axis=0)
 
     return {"k": ring(kv["k"]), "v": ring(kv["v"])}
 

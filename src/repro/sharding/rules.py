@@ -177,8 +177,8 @@ def batch_specs(batch_shapes: dict, mesh: Mesh, group_stacked: bool = False,
 
 
 def cache_pspecs(cache_shapes, mesh: Mesh):
-    """Decode-cache specs. Leaves are stacked (n_periods, B, S, ...) for
-    attention K/V, (n_periods, B, H, hd, N)/(n_periods, B, K-1, conv) for SSD,
+    """Decode-cache specs. Leaves are stacked (n_periods, S, B, KV, hd) for
+    attention K/V (sequence-major), (n_periods, B, H, hd, N)/(n_periods, B, K-1, conv) for SSD,
     plus scalars and the enc memory (B, S, D).
 
     Batch shards over (pod, data) when divisible; attention cache sequence
@@ -195,11 +195,11 @@ def cache_pspecs(cache_shapes, mesh: Mesh):
             b = maybe_axis(leaf.shape[0], bax, mesh)
             return P(b, None, maybe_axis(leaf.shape[2], "model", mesh))
         if re.search(r"/(k|v)$", ps):
-            # (n_periods, B, S, KV, hd)
-            _, B, S, KV, hd = leaf.shape
+            # (n_periods, S, B, KV, hd)
+            _, S, B, KV, hd = leaf.shape
             b = maybe_axis(B, bax, mesh)
             s = maybe_axis(S, "model", mesh)
-            return P(None, b, s, None, None)
+            return P(None, s, b, None, None)
         if ps.endswith("ssm"):
             _, B, H, hd, N = leaf.shape
             return P(None, maybe_axis(B, bax, mesh), maybe_axis(H, "model", mesh),

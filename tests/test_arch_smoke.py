@@ -98,6 +98,34 @@ def test_decode_matches_forward(arch):
 
 
 @pytest.mark.parametrize("arch", ["h2o-danube-1.8b", "llama4-scout-17b-a16e"])
+def test_decode_matches_forward_past_the_window(arch):
+    """40 decode steps after a 6-token prompt wrap the reduced ring of 16
+    slots twice and cross the chunk boundaries at 16 and 32; every step's
+    logits match the full forward's."""
+    cfg = get_config(arch).reduced()
+    assert 16 in (cfg.sliding_window, cfg.attn_chunk)
+    if cfg.moe:  # drop-free reference for exactness
+        cfg = replace(cfg, moe=replace(cfg.moe,
+                                       capacity_factor=float(cfg.moe.num_experts) / cfg.moe.top_k))
+    params = init_params(jax.random.PRNGKey(1), cfg)
+    B, S, steps = 2, 6, 40
+    key = jax.random.PRNGKey(7)
+    toks = jax.random.randint(key, (B, S + steps), 0, cfg.vocab_size)
+    batch = _batch(cfg, B, S, key)
+    batch["tokens"] = toks[:, :S]
+    full = dict(batch)
+    full["tokens"] = toks
+    logits_full, _ = forward_train(params, cfg, full)
+    _, cache = prefill(params, cfg, batch, cache_len=S + steps)
+    step = jax.jit(lambda c, t: decode_step(params, cfg, t, c))
+    for t in range(S, S + steps):
+        lg, cache = step(cache, toks[:, t:t + 1])
+        a = np.asarray(logits_full[:, t, :], np.float32)
+        b = np.asarray(lg[:, 0, :], np.float32)
+        assert np.max(np.abs(a - b)) / (np.max(np.abs(a)) + 1e-9) < 1e-4, t
+
+
+@pytest.mark.parametrize("arch", ["h2o-danube-1.8b", "llama4-scout-17b-a16e"])
 def test_windowed_cache_is_bounded(arch):
     """SWA/chunked archs must hold a window-sized cache, not seq_len."""
     cfg = get_config(arch)
@@ -105,7 +133,7 @@ def test_windowed_cache_is_bounded(arch):
     for j, kind in enumerate(cfg.layer_kinds()[: len(specs["layers"])]):
         leaf = specs["layers"][f"pos{j}"]
         if "k" in leaf:
-            S = leaf["k"].shape[2]
+            S = leaf["k"].shape[1]   # (n_periods, S, B, KV, hd)
             if kind == "attn_swa":
                 assert S <= cfg.sliding_window
             elif kind == "attn_chunk":

@@ -9,10 +9,16 @@ compiled program really holds the kernel (``tpu_custom_call``) rather than an
 interpreted fallback.  The mode comes from the same backend helper the
 program uses, asked for 'tpu'.
 
+The serving decode step is compiled the same way, at Qwen1.5-4B's widths,
+and its optimized HLO is read for copies of the KV cache: the step must
+write its token into the stacked cache in place.
+
 The topology is described only inside the module fixture: loading the TPU
 library at import or collection time would make test workers disagree on
 what exists.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -129,3 +135,95 @@ def test_efbv_sync_with_kernel_compressor_compiles_on_mesh(topo):
             k, g, s, c, lam, nu, bucket_size=0)).lower(key, grads_g, state)
     compiled = lowered.compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# -- the decode program's structure -------------------------------------------
+# Qwen1.5-4B's widths (d_model 2560, 20 heads of 128 with QKV bias, bf16) at
+# 4 layers, 8 slots and a 768-position cache: one layer's K (or V) cache is
+# 8 x 768 x 20 x 128 elements, the stack of them 4 times that.
+DECODE_LAYERS, SLOTS, CACHE_LEN = 4, 8, 768
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) ", re.M)
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\((.*)$")
+
+
+def _computations(hlo: str) -> dict:
+    """{name: instruction lines} of every computation of an HLO module."""
+    out, name = {}, None
+    for line in hlo.splitlines():
+        m = _COMPUTATION.match(line)
+        if m and line.rstrip().endswith("{"):
+            name = m.group(1)
+            out[name] = []
+        elif name is not None and line.startswith(" "):
+            out[name].append(line)
+    return out
+
+
+def _instructions(lines):
+    """(name, element count, opcode, rest of line) of each array-valued
+    instruction."""
+    for line in lines:
+        m = _INSTRUCTION.match(line)
+        if m:
+            dims = [int(d) for d in m.group(2).split(",") if d]
+            yield m.group(1), int(np.prod(dims)), m.group(3), m.group(4)
+
+
+def _copies(comps: dict, opcode: str, rest: str) -> bool:
+    """Whether an instruction is a copy: a ``copy``, or a fusion that holds
+    one (a relayout that XLA fused with the op it feeds)."""
+    if opcode == "copy":
+        return True
+    m = re.search(r"calls=%(\S+?)[,\s]", rest)
+    return (opcode == "fusion" and m is not None and
+            any(op in ("copy", "transpose")
+                for _, _, op, _ in _instructions(comps[m.group(1)])))
+
+
+@pytest.fixture(scope="module")
+def decode_hlo(one_chip):
+    from dataclasses import replace
+
+    from repro import models
+    from repro.configs import get_config
+
+    cfg = replace(get_config("qwen1.5-4b"), num_layers=DECODE_LAYERS)
+
+    def on(tree):
+        return jax.tree_util.tree_map(
+            lambda a: _sds(a.shape, a.dtype, one_chip), tree)
+
+    params = jax.eval_shape(lambda k: models.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    cache = models.cache_specs(cfg, SLOTS, CACHE_LEN)
+    token = _sds((SLOTS, 1), jnp.int32, one_chip)
+    compiled = jax.jit(lambda p, t, c: models.decode_step(p, cfg, t, c)).lower(
+        on(params), token, on(cache)).compile()
+    layer = SLOTS * CACHE_LEN * cfg.num_kv_heads * cfg.head_dim
+    return compiled.as_text(), layer
+
+
+def test_decode_writes_the_cache_in_place(decode_hlo):
+    """The layer loop copies no layer's K/V cache out of the stack and writes
+    no new stack: outside fused computations no instruction produces a
+    layer's whole cache, and the only copies of the stacked K and V are the
+    one each that an undonated argument needs, outside the loop."""
+    hlo, layer = decode_hlo
+    comps = _computations(hlo)
+    fused = set(re.findall(r"(?:calls|to_apply)=%([\w.\-]+)", hlo))
+    bodies = set(re.findall(r"body=%([\w.\-]+)", hlo))
+    assert bodies, "the layer scan is no longer a loop"
+    layer_sized, stack_copies = [], []
+    for comp, lines in comps.items():
+        if comp in fused:
+            continue
+        for name, n, opcode, rest in _instructions(lines):
+            if n == layer:
+                layer_sized.append(name)
+            if n == DECODE_LAYERS * layer and _copies(comps, opcode, rest):
+                stack_copies.append((name, comp in bodies))
+    assert not layer_sized, layer_sized
+    assert len(stack_copies) <= 2, stack_copies
+    assert not any(in_loop for _, in_loop in stack_copies), stack_copies
