@@ -60,6 +60,9 @@ class ModelConfig:
     qkv_bias: bool = False            # qwen1.5
     qk_norm: bool = False             # chameleon
     rope_theta: float = 10000.0
+    nope: bool = False                # no positional encoding in any layer (granite-4.0-h)
+    # softmax scale of attention; None => 1/sqrt(head_dim)
+    attention_multiplier: Optional[float] = None
     sliding_window: int = 0           # >0 => SWA (h2o-danube)
     attn_chunk: int = 0               # >0 => chunked-local attention (llama4)
     # layer schedule: None => all ATTN_GLOBAL (or per-arch default); else a
@@ -80,6 +83,10 @@ class ModelConfig:
     # --- multimodal early-fusion stub ---
     vision_tokens: int = 0            # llama4: projected patch embeddings count
     audio_frontend: bool = False      # seamless: frame embeddings replace src tokens
+    # --- muP-style multipliers (granite): at 1.0 they add no operation ---
+    embedding_multiplier: float = 1.0     # scales the token embeddings
+    residual_multiplier: float = 1.0      # scales each sublayer's output
+    logits_scaling: float = 1.0           # logits are divided by it
     # --- misc ---
     tie_embeddings: bool = False
     norm_eps: float = 1e-5

@@ -85,8 +85,10 @@ BANDED = False
 
 def _flash_attention(q, k, v, kind: str, window: int, chunk: int,
                      q_offset: int = 0, block_q: Optional[int] = None,
-                     block_k: Optional[int] = None):
+                     block_k: Optional[int] = None,
+                     scale: Optional[float] = None):
     """2D-tiled (flash-style) softmax attention. q (B,Sq,H,hd); k,v (B,Sk,KV,hd).
+    ``scale`` multiplies the scores; None is 1/sqrt(hd).
 
     Outer scan over query tiles, inner scan over KV tiles keeping a running
     (max, denom, accum) per query.  The inner body is wrapped in
@@ -97,7 +99,8 @@ def _flash_attention(q, k, v, kind: str, window: int, chunk: int,
     B, Sq, H, hd = q.shape
     _, Sk, KV, _ = k.shape
     G = H // KV
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
 
     bq = min(block_q or BLOCK_Q, Sq)
     bk = min(block_k or BLOCK_K, Sk)
@@ -181,14 +184,15 @@ def _flash_attention(q, k, v, kind: str, window: int, chunk: int,
 
 def attention_train(params, x, *, cfg_attn: dict, positions=None):
     """cfg_attn keys: num_heads num_kv_heads head_dim kind window chunk
-    qk_norm use_rope rope_theta."""
+    qk_norm use_rope rope_theta scale (None: 1/sqrt(head_dim))."""
     B, S, _ = x.shape
     if positions is None:
         positions = jnp.arange(S)[None, :]
     q, k, v = _project_qkv(params, x, cfg_attn["num_heads"], cfg_attn["num_kv_heads"],
                            cfg_attn["head_dim"], cfg_attn["qk_norm"], cfg_attn["use_rope"],
                            positions, cfg_attn["rope_theta"])
-    out = _flash_attention(q, k, v, cfg_attn["kind"], cfg_attn["window"], cfg_attn["chunk"])
+    out = _flash_attention(q, k, v, cfg_attn["kind"], cfg_attn["window"], cfg_attn["chunk"],
+                           scale=cfg_attn["scale"])
     return out.reshape(B, S, -1) @ params["wo"]
 
 
@@ -201,7 +205,8 @@ def attention_prefill(params, x, *, cfg_attn: dict):
     q, k, v = _project_qkv(params, x, cfg_attn["num_heads"], cfg_attn["num_kv_heads"],
                            cfg_attn["head_dim"], cfg_attn["qk_norm"], cfg_attn["use_rope"],
                            positions, cfg_attn["rope_theta"])
-    out = _flash_attention(q, k, v, cfg_attn["kind"], cfg_attn["window"], cfg_attn["chunk"])
+    out = _flash_attention(q, k, v, cfg_attn["kind"], cfg_attn["window"], cfg_attn["chunk"],
+                           scale=cfg_attn["scale"])
     out = out.reshape(B, S, -1) @ params["wo"]
     return out, {"k": k, "v": v}
 
@@ -268,7 +273,9 @@ def attention_decode(params, x, cache: dict, pos: jax.Array, layer, *,
     valid = (written & live).astype(jnp.float32)
     bias = jnp.where(valid > 0, 0.0, NEG_INF)
 
-    scale = 1.0 / math.sqrt(hd)
+    scale = cfg_attn["scale"]
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
     qf = (q.astype(jnp.float32) * scale).reshape(B, 1, KV, H // KV, hd)
     s = jnp.einsum("bqkgh,nbkh->bqkgn", qf, k.astype(jnp.float32))
     s = s + bias[None, None, None, None, :]

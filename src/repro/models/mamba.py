@@ -10,6 +10,10 @@ state.
 Parameterization follows the reference: in_proj -> [z, x, B, C, dt], causal
 depthwise conv over (x,B,C), A scalar-per-head (negative via -exp(a_log)),
 per-head dt bias, D skip, gated RMSNorm before out_proj.
+
+The conv, the chunked SSD and the decode's state step are named scopes
+(``mamba/conv``, ``mamba/ssd``, ``mamba/state_step``) in each HLO
+instruction's metadata.
 """
 from __future__ import annotations
 
@@ -19,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.layers import _dense_init, init_rmsnorm, rmsnorm
+from repro.obs.trace import annotate
 
 
 def mamba_dims(d_model: int, cfg) -> dict:
@@ -133,7 +138,8 @@ def mamba_forward(params, u, cfg, d_model: int, return_cache: bool = False):
     from repro.sharding.context import constrain_named
 
     z, xBC_raw, dt = _split_proj(params, u, cfg, dims)
-    xBC = _causal_conv(params, xBC_raw, cfg)
+    with annotate("mamba/conv"):
+        xBC = _causal_conv(params, xBC_raw, cfg)
     x, B_, C_ = jnp.split(xBC, [di, di + G * N], axis=-1)
     # optional SSD head sharding (perf variant): keeps the (B,K,Q,Q,H)
     # intra-chunk tensors model-sharded over heads instead of replicated
@@ -151,7 +157,8 @@ def mamba_forward(params, u, cfg, d_model: int, return_cache: bool = False):
         dt = jnp.pad(dt, ((0, 0), (0, padlen), (0, 0)))
         B_ = jnp.pad(B_, ((0, 0), (0, padlen), (0, 0), (0, 0)))
         C_ = jnp.pad(C_, ((0, 0), (0, padlen), (0, 0), (0, 0)))
-    y, state = _ssd_chunked(x, dt, A, B_, C_, params["D"], chunk)
+    with annotate("mamba/ssd"):
+        y, state = _ssd_chunked(x, dt, A, B_, C_, params["D"], chunk)
     y = y[:, :S]
 
     y = y.reshape(Bsz, S, di).astype(u.dtype)
@@ -184,11 +191,12 @@ def mamba_decode(params, u, cache: dict, cfg, d_model: int):
     Bsz = u.shape[0]
 
     z, xBC, dt = _split_proj(params, u, cfg, dims)     # (B,1,*)
-    conv_in = jnp.concatenate([cache["conv"], xBC], axis=1)  # (B,K,conv_dim)
-    w = params["conv_w"]                                # (K, conv_dim)
-    conv_out = jnp.sum(conv_in * w[None], axis=1, keepdims=True) + params["conv_b"]
-    xBC_t = jax.nn.silu(conv_out)                       # (B,1,conv_dim)
-    new_conv = conv_in[:, 1:]
+    with annotate("mamba/conv"):
+        conv_in = jnp.concatenate([cache["conv"], xBC], axis=1)  # (B,K,conv_dim)
+        w = params["conv_w"]                            # (K, conv_dim)
+        conv_out = jnp.sum(conv_in * w[None], axis=1, keepdims=True) + params["conv_b"]
+        xBC_t = jax.nn.silu(conv_out)                   # (B,1,conv_dim)
+        new_conv = conv_in[:, 1:]
 
     x, B_, C_ = jnp.split(xBC_t[:, 0], [di, di + G * N], axis=-1)
     x = x.reshape(Bsz, H, hd).astype(jnp.float32)
@@ -201,9 +209,10 @@ def mamba_decode(params, u, cache: dict, cfg, d_model: int):
     rep = H // G
     Brep = jnp.repeat(B_, rep, axis=1)                  # (B,H,N)
     Crep = jnp.repeat(C_, rep, axis=1)
-    state = cache["ssm"] * da[..., None, None] + jnp.einsum(
-        "bh,bhd,bhn->bhdn", dt_t, x, Brep)
-    y = jnp.einsum("bhdn,bhn->bhd", state, Crep) + x * params["D"][None, :, None]
+    with annotate("mamba/state_step"):
+        state = cache["ssm"] * da[..., None, None] + jnp.einsum(
+            "bh,bhd,bhn->bhdn", dt_t, x, Brep)
+        y = jnp.einsum("bhdn,bhn->bhd", state, Crep) + x * params["D"][None, :, None]
 
     y = y.reshape(Bsz, 1, di).astype(u.dtype)
     y = rmsnorm(params["norm"], y * jax.nn.silu(z))

@@ -64,13 +64,36 @@ def _attn_cfg(cfg: ModelConfig, kind: str) -> dict:
         chunk=cfg.attn_chunk,
         qk_norm=cfg.qk_norm,
         # llama4 iRoPE: global (non-chunked) layers are NoPE
-        use_rope=not (cfg.attn_chunk > 0 and kind == "attn"),
+        use_rope=not cfg.nope and not (cfg.attn_chunk > 0 and kind == "attn"),
         rope_theta=cfg.rope_theta,
+        scale=cfg.attention_multiplier,
     )
 
 
 def model_dtype(cfg: ModelConfig):
     return jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
+
+
+# The muP-style multipliers are static: at their default of 1.0 they add no
+# operation, so a model without them compiles to the same program.
+def _scale_embeddings(cfg: ModelConfig, x):
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    return x
+
+
+def _residual(cfg: ModelConfig, h):
+    """A sublayer's output as it is added to the residual stream."""
+    if cfg.residual_multiplier != 1.0:
+        h = h * cfg.residual_multiplier
+    return h
+
+
+def _logits(params, cfg: ModelConfig, x):
+    logits = unembed(params["embed"], x)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 # ---------------------------------------------------------------------------
@@ -154,7 +177,7 @@ def _apply_block_train(bp, cfg: ModelConfig, kind: str, use_moe: bool, x,
         h = mamba_lib.mamba_train(bp["mamba"], h, cfg.mamba, cfg.d_model)
     else:
         h = attn_lib.attention_train(bp["attn"], h, cfg_attn=_attn_cfg(cfg, kind))
-    x = x + h
+    x = x + _residual(cfg, h)
     if cfg.cross_attn and enc_out is not None:
         h = rmsnorm(bp["norm_x"], x, cfg.norm_eps)
         h = _cross_attention(bp["xattn"], h, enc_out, cfg)
@@ -169,7 +192,7 @@ def _apply_block_train(bp, cfg: ModelConfig, kind: str, use_moe: bool, x,
             aux = aux + a
         else:
             h = mlp(bp["mlp"], h, act=cfg.mlp_act, gated=cfg.mlp_gated)
-        x = x + h
+        x = x + _residual(cfg, h)
     return x, aux
 
 
@@ -286,6 +309,7 @@ def forward_train(params, cfg: ModelConfig, batch: dict, remat: str = "dots"):
         x = batch["inputs_embeds"]
     else:
         x = embed(params["embed"], tokens)
+    x = _scale_embeddings(cfg, x)
 
     if cfg.vision_tokens and "vision_embeds" in batch:
         vis = batch["vision_embeds"] @ params["vision_proj"]
@@ -308,7 +332,7 @@ def forward_train(params, cfg: ModelConfig, batch: dict, remat: str = "dots"):
     x, auxes = stack_scan(_remat_wrap(period_body, remat), _constrain(x),
                           params["blocks"])
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = unembed(params["embed"], x)
+    logits = _logits(params, cfg, x)
     return logits, jnp.sum(auxes)
 
 
@@ -361,7 +385,7 @@ def _apply_block_decode(bp, cfg: ModelConfig, kind: str, use_moe: bool, x, cache
     else:
         h, caches = attn_lib.attention_decode(
             bp["attn"], h, caches, pos, layer, cfg_attn=_attn_cfg(cfg, kind))
-    x = x + h
+    x = x + _residual(cfg, h)
     if cfg.cross_attn and enc_memory is not None:
         h = rmsnorm(bp["norm_x"], x, cfg.norm_eps)
         x = x + _cross_attention(bp["xattn"], h, enc_memory, cfg)
@@ -375,7 +399,7 @@ def _apply_block_decode(bp, cfg: ModelConfig, kind: str, use_moe: bool, x, cache
                 no_drop=True)
         else:
             h = mlp(bp["mlp"], h, act=cfg.mlp_act, gated=cfg.mlp_gated)
-        x = x + h
+        x = x + _residual(cfg, h)
     return x, caches
 
 
@@ -387,7 +411,7 @@ def decode_step(params, cfg: ModelConfig, token: jax.Array, cache: dict):
     layer's cache out and stacking a new one."""
     P, n_periods, pos_kinds, pos_moe = period_info(cfg)
     pos = cache["pos"]
-    x = embed(params["embed"], token)
+    x = _scale_embeddings(cfg, embed(params["embed"], token))
     enc_memory = cache.get("enc_memory")
 
     def period_body(carry, scanned):
@@ -404,7 +428,7 @@ def decode_step(params, cfg: ModelConfig, token: jax.Array, cache: dict):
         period_body, (x, cache["layers"]),
         (params["blocks"], jnp.arange(n_periods, dtype=jnp.int32)))
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = unembed(params["embed"], x)
+    logits = _logits(params, cfg, x)
     new_cache = dict(cache)
     new_cache["layers"] = new_layer_caches
     new_cache["pos"] = pos + 1
@@ -450,7 +474,7 @@ def prefill(params, cfg: ModelConfig, batch: dict, remat: str = "dots",
     tokens = batch["tokens"]
     B, S = tokens.shape
     cache_len = max(cache_len, S + 1)
-    x = embed(params["embed"], tokens)
+    x = _scale_embeddings(cfg, embed(params["embed"], tokens))
     if cfg.vision_tokens and "vision_embeds" in batch:
         vis = batch["vision_embeds"] @ params["vision_proj"]
         nv = vis.shape[1]
@@ -473,7 +497,7 @@ def prefill(params, cfg: ModelConfig, batch: dict, remat: str = "dots",
                 h, kv = attn_lib.attention_prefill(bp["attn"], h, cfg_attn=acfg)
                 cache_j = _ring_from_prefill(kv, acfg, S, cache_len)
             caches[f"pos{j}"] = cache_j
-            x = x + h
+            x = x + _residual(cfg, h)
             if cfg.cross_attn and enc_out is not None:
                 hx = rmsnorm(bp["norm_x"], x, cfg.norm_eps)
                 x = x + _cross_attention(bp["xattn"], hx, enc_out, cfg)
@@ -487,13 +511,13 @@ def prefill(params, cfg: ModelConfig, batch: dict, remat: str = "dots",
                         shared_expert=cfg.moe.shared_expert)
                 else:
                     h2 = mlp(bp["mlp"], h2, act=cfg.mlp_act, gated=cfg.mlp_gated)
-                x = x + h2
+                x = x + _residual(cfg, h2)
         return _constrain(x), caches
 
     x, layer_caches = stack_scan(_remat_wrap(period_body, remat), _constrain(x),
                                  params["blocks"])
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = unembed(params["embed"], x[:, -1:])
+    logits = _logits(params, cfg, x[:, -1:])
     cache = {"layers": layer_caches, "pos": jnp.asarray(S, jnp.int32)}
     if cfg.enc_layers:
         cache["enc_memory"] = enc_out

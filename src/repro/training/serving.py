@@ -9,9 +9,12 @@ reduced scale on CPU (examples/serve_decode.py drives it).
 
 Slot semantics: one shared cache of capacity ``max_len``; per-slot position
 offsets are handled by left-padding prompts into the slot at prefill time and
-masking finished slots. Prefill for a refill batches all newly admitted
-requests together (prefill and decode alternate — the standard
-continuous-batching compromise without paged attention).
+masking finished slots.  A model with SSM layers cannot be left-padded (the
+pad tokens would enter the recurrent state), so there every admission must
+re-prefill contexts of one length, as closed waves of equal prompts do.
+Prefill for a refill batches all newly admitted requests together (prefill
+and decode alternate — the standard continuous-batching compromise without
+paged attention).
 
 Subclass hooks (``repro.serve.engine.PersonalizedBatcher`` uses all four):
 ``_build_model`` constructs the jitted steps, ``_model_prefill`` /
@@ -26,7 +29,8 @@ recorder when it is on and written into the profiler's trace while a
 
     serve/step                  the whole tick
       serve/admit               filling free slots (tags new, live)
-        serve/prefill           dispatch of the prefill (tags rows, tokens)
+        serve/prefill           dispatch of the prefill (tags rows, tokens,
+                                ssm_state_bytes, kv_cache_bytes)
         serve/fetch             the host's wait for the prefill's tokens
       serve/decode              staging the tokens and dispatching the step
       serve/fetch               the host's wait for the sampled tokens
@@ -46,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.configs.base import MAMBA
 from repro.obs import trace as obs_trace
 
 
@@ -67,16 +72,42 @@ class ServeStats:
     decode_steps: int = 0
     prefills: int = 0
     tokens_out: int = 0
+    # the batch cache held: SSM layers' recurrent state (state and conv
+    # tail) and attention layers' K/V, in bytes
+    ssm_state_bytes: int = 0
+    kv_cache_bytes: int = 0
+
+
+def cache_bytes(cache) -> tuple:
+    """(recurrent-state bytes, K/V bytes) of a decode cache, from the
+    arrays' shapes (no device sync)."""
+    ssm = kv = 0
+    for path, a in jax.tree_util.tree_flatten_with_path(cache["layers"])[0]:
+        name = getattr(path[-1], "key", None)
+        if name in ("ssm", "conv"):
+            ssm += a.nbytes
+        elif name in ("k", "v"):
+            kv += a.nbytes
+    return ssm, kv
 
 
 class ContinuousBatcher:
-    """Fixed-slot continuous batching over (prefill, decode_step)."""
+    """Fixed-slot continuous batching over (prefill, decode_step).
 
-    def __init__(self, cfg, params, n_slots: int = 4, max_len: int = 128):
+    With ``donate_cache`` the decode step is handed the batch cache to
+    write its successor into, in place: no step allocates a second cache
+    beside the first, and the cache a step was given is deleted once it
+    has been dispatched.  Without it (the default) each step's cache is a
+    new array and the old one stays readable."""
+
+    def __init__(self, cfg, params, n_slots: int = 4, max_len: int = 128,
+                 donate_cache: bool = False):
         self.cfg = cfg
         self.params = params
+        self._recurrent = MAMBA in cfg.layer_kinds()
         self.n_slots = n_slots
         self.max_len = max_len
+        self.donate_cache = donate_cache
         self._build_model()
         self.queue: Deque[Request] = deque()
         self.slots: List[Optional[Request]] = [None] * n_slots
@@ -95,7 +126,8 @@ class ContinuousBatcher:
             return models.decode_step(p, self.cfg, t, c)
 
         self._prefill = jax.jit(prefill)
-        self._decode = jax.jit(decode)
+        self._decode = jax.jit(decode,
+                               donate_argnums=2 if self.donate_cache else ())
 
     def _model_prefill(self, batch):
         return self._prefill(self.params, batch)
@@ -126,6 +158,8 @@ class ContinuousBatcher:
         free = self._free_slots()
         if not free or not self.queue:
             return
+        if self._recurrent:
+            self._check_unpadded(free)
         with obs_trace.span("serve/admit") as sp:
             n_new = 0
             for i in free:
@@ -153,14 +187,36 @@ class ContinuousBatcher:
             if self.cfg.vision_tokens:
                 batch["vision_embeds"] = jnp.zeros(
                     (self.n_slots, self.cfg.vision_tokens, self.cfg.d_model))
+            # the prefill rebuilds the whole batch cache: free the old one
+            # first, so that the two are never on the device together
+            self.cache = None
             with obs_trace.span("serve/prefill", rows=len(live),
-                                tokens=int(maxlen)):
+                                tokens=int(maxlen)) as pf:
                 logits, self.cache = self._model_prefill(batch)
+                (self.stats.ssm_state_bytes,
+                 self.stats.kv_cache_bytes) = cache_bytes(self.cache)
+                pf.tag(ssm_state_bytes=self.stats.ssm_state_bytes,
+                       kv_cache_bytes=self.stats.kv_cache_bytes)
             first = jnp.argmax(logits[:, -1, :self.cfg.vocab_size], -1)
             with obs_trace.span("serve/fetch"):
                 first = np.asarray(first)
             self.next_tok = first[:, None].astype(np.int32)
             self.stats.prefills += 1
+
+    def _check_unpadded(self, free: List[int]) -> None:
+        """Refuse an admission whose re-prefill would left-pad a context:
+        the pad tokens would enter the SSM layers' recurrent state, which no
+        mask keeps them out of."""
+        incoming = list(self.queue)[:len(free)]
+        staying = [r for r in self.slots if r is not None and not r.done]
+        lengths = sorted({len(r.prompt) + len(r.generated)
+                          for r in staying + incoming})
+        if len(lengths) > 1:
+            raise ValueError(
+                f"{self.cfg.name} has SSM layers: re-prefilling contexts of "
+                f"{lengths} tokens together would left-pad the shorter ones "
+                f"into the recurrent state; admit requests of one context "
+                f"length at a time (closed waves)")
 
     # -- decode --------------------------------------------------------------
     def step(self) -> int:

@@ -16,6 +16,7 @@ ARCHS = [
     "llama4-scout-17b-a16e", "chameleon-34b", "qwen1.5-110b",
     "seamless-m4t-large-v2", "mamba2-2.7b", "qwen1.5-4b", "dbrx-132b",
     "jamba-1.5-large-398b", "h2o-danube-1.8b", "nemotron-4-15b",
+    "granite-4.0-h-micro",
 ]
 
 

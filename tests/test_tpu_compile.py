@@ -11,13 +11,16 @@ program uses, asked for 'tpu'.
 
 The serving decode step is compiled the same way, at Qwen1.5-4B's widths,
 and its optimized HLO is read for copies of the KV cache: the step must
-write its token into the stacked cache in place.
+write its token into the stacked cache in place.  granite-4.0-h-micro's
+decode, as the batcher builds it, must alias its whole cache when the
+batcher donates it, and copy no SSM state then.
 
 The topology is described only inside the module fixture: loading the TPU
 library at import or collection time would make test workers disagree on
 what exists.
 """
 import re
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -227,3 +230,43 @@ def test_decode_writes_the_cache_in_place(decode_hlo):
     assert not layer_sized, layer_sized
     assert len(stack_copies) <= 2, stack_copies
     assert not any(in_loop for _, in_loop in stack_copies), stack_copies
+
+
+# granite-4.0-h-micro whole (36 Mamba-2 layers, 4 attention) at 32 slots and
+# a 1536-position cache, as the hybrid serving cell runs it
+HYBRID_SLOTS, HYBRID_CACHE_LEN = 32, 1536
+
+
+@pytest.mark.parametrize("donate", [False, True])
+def test_donated_decode_writes_the_ssm_state_in_place(one_chip, donate):
+    """``ContinuousBatcher(donate_cache=True)``'s decode program aliases the
+    whole cache to its output and copies no stacked SSM state; undonated,
+    each stack is copied before the step writes it."""
+    from repro import models
+    from repro.configs import get_config
+    from repro.training.serving import ContinuousBatcher
+
+    cfg = get_config("granite-4.0-h-micro")
+    params = jax.eval_shape(lambda k: models.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    cache = models.cache_specs(cfg, HYBRID_SLOTS, HYBRID_CACHE_LEN)
+    on = partial(jax.tree_util.tree_map,
+                 lambda a: _sds(a.shape, a.dtype, one_chip))
+    batcher = ContinuousBatcher(cfg, None, n_slots=HYBRID_SLOTS,
+                                max_len=HYBRID_CACHE_LEN, donate_cache=donate)
+    compiled = batcher._decode.lower(
+        on(params), _sds((HYBRID_SLOTS, 1), jnp.int32, one_chip),
+        on(cache)).compile()
+    states = [a for path, a in
+              jax.tree_util.tree_flatten_with_path(cache["layers"])[0]
+              if path[-1].key == "ssm"]
+    state_copies = [name for lines in _computations(compiled.as_text()).values()
+                    for name, n, opcode, _ in _instructions(lines)
+                    if opcode == "copy" and n == states[0].size]
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(cache["layers"]))
+    aliased = compiled.memory_analysis().alias_size_in_bytes
+    if donate:
+        assert aliased >= cache_bytes and not state_copies, state_copies
+    else:
+        assert aliased == 0 and len(state_copies) == len(states)
