@@ -7,6 +7,8 @@ row/col sums, symwanda normalizers), and expose drop-in backends:
   * ``quantize_dequantize``  — compressor backend (core/compressors.qsgd)
   * ``prune_nm``             — N:M backend for core/symwanda.mask_nm
   * ``prune_scored``         — fused score+mask backend for core/symwanda.prune
+  * ``ssm_state_step``       — Mamba-2 decode state step, in place
+                               (models/mamba.mamba_decode)
 
 ``interpret=None`` leaves the choice to kernels/backend.py: compiled on a
 TPU, interpreted on the CPU.  Passing True or False overrides it.
@@ -22,6 +24,7 @@ import jax.numpy as jnp
 from repro.kernels import bitpack as _bp
 from repro.kernels import nm_prune as _nm
 from repro.kernels import quant8 as _q8
+from repro.kernels import ssm_step as _ss
 from repro.kernels import wanda_score as _ws
 from repro.kernels import ref as _ref
 
@@ -209,3 +212,18 @@ def prune_scored(w: jax.Array, X: jax.Array, mode: str = "wanda",
         out, mask = _ws.wanda_prune_2d(wp, xn_p, tau_p, mode="wanda",
                                        interpret=interpret)
     return out[:r, :c], mask[:r, :c]
+
+
+# ---------------------------------------------------------------------------
+# Mamba-2 decode state step
+# ---------------------------------------------------------------------------
+@partial(jax.jit, static_argnames=("interpret",))
+def ssm_state_step(state: jax.Array, layer: jax.Array, da: jax.Array,
+                   dt: jax.Array, x: jax.Array, b: jax.Array, c: jax.Array,
+                   interpret: Optional[bool] = None):
+    """One decode step of layer ``layer`` of the stacked f32 SSM state
+    (P, B, H, hd, N), written where it lies: da, dt (B, H); x (B, H, hd);
+    b, c (B, G, N).  Returns (the stack, aliased to ``state``, and the
+    readout y (B, H, hd) without the ``D`` skip)."""
+    return _ss.state_step(state, layer, da, dt[..., None] * x, b, c,
+                          interpret=interpret)

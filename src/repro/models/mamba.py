@@ -13,15 +13,21 @@ per-head dt bias, D skip, gated RMSNorm before out_proj.
 
 The conv, the chunked SSD and the decode's state step are named scopes
 (``mamba/conv``, ``mamba/ssd``, ``mamba/state_step``) in each HLO
-instruction's metadata.
+instruction's metadata.  On a TPU the decode's state step is one Pallas
+kernel that reads and writes each layer's state once, in the stack it
+lies in (``kernels/ssm_step.py``); ``fused_state_steps`` counts the layers
+it serves while a decode traces.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import backend as kernel_backend
 from repro.models.layers import _dense_init, init_rmsnorm, rmsnorm
 from repro.obs.trace import annotate
 
@@ -183,8 +189,10 @@ def mamba_cache_spec(d_model: int, cfg, batch: int, dtype):
     }
 
 
-def mamba_decode(params, u, cache: dict, cfg, d_model: int):
-    """One-token step. u (B,1,d_model); cache {ssm (B,H,hd,N), conv (B,K-1,conv_dim)}."""
+def mamba_decode(params, u, caches: dict, layer, cfg, d_model: int):
+    """One-token step of layer ``layer`` of this position's stacked caches
+    {ssm (P,B,H,hd,N), conv (P,B,K-1,conv_dim)}; u (B,1,d_model).  Returns
+    (out, caches) with that layer's state and conv tail replaced in place."""
     dims = mamba_dims(d_model, cfg)
     di, H, G, N = dims["d_inner"], dims["n_heads"], cfg.n_groups, cfg.d_state
     hd = cfg.head_dim
@@ -192,11 +200,14 @@ def mamba_decode(params, u, cache: dict, cfg, d_model: int):
 
     z, xBC, dt = _split_proj(params, u, cfg, dims)     # (B,1,*)
     with annotate("mamba/conv"):
-        conv_in = jnp.concatenate([cache["conv"], xBC], axis=1)  # (B,K,conv_dim)
+        conv = jax.lax.dynamic_index_in_dim(caches["conv"], layer, 0,
+                                            keepdims=False)
+        conv_in = jnp.concatenate([conv, xBC], axis=1)  # (B,K,conv_dim)
         w = params["conv_w"]                            # (K, conv_dim)
         conv_out = jnp.sum(conv_in * w[None], axis=1, keepdims=True) + params["conv_b"]
         xBC_t = jax.nn.silu(conv_out)                   # (B,1,conv_dim)
-        new_conv = conv_in[:, 1:]
+        new_conv = jax.lax.dynamic_update_index_in_dim(
+            caches["conv"], conv_in[:, 1:], layer, 0)
 
     x, B_, C_ = jnp.split(xBC_t[:, 0], [di, di + G * N], axis=-1)
     x = x.reshape(Bsz, H, hd).astype(jnp.float32)
@@ -206,15 +217,80 @@ def mamba_decode(params, u, cache: dict, cfg, d_model: int):
     A = -jnp.exp(params["a_log"])
     da = jnp.exp(dt_t * A[None])                        # (B,H)
 
-    rep = H // G
-    Brep = jnp.repeat(B_, rep, axis=1)                  # (B,H,N)
-    Crep = jnp.repeat(C_, rep, axis=1)
+    step = _kernel_state_step if _fuses_state_step(hd, N) else _state_step
     with annotate("mamba/state_step"):
-        state = cache["ssm"] * da[..., None, None] + jnp.einsum(
-            "bh,bhd,bhn->bhdn", dt_t, x, Brep)
-        y = jnp.einsum("bhdn,bhn->bhd", state, Crep) + x * params["D"][None, :, None]
+        state, y = step(caches["ssm"], layer, da, dt_t, x, B_, C_)
+        y = y + x * params["D"][None, :, None]
 
     y = y.reshape(Bsz, 1, di).astype(u.dtype)
     y = rmsnorm(params["norm"], y * jax.nn.silu(z))
     out = y @ params["out_proj"]
     return out, {"ssm": state, "conv": new_conv}
+
+
+def _state_step(ssm, layer, da, dt, x, B_, C_):
+    """Layer ``layer`` of the stacked state (P,B,H,hd,N) steps in
+    ``jax.numpy``: h' = da h + dt x B, y = C h'.  da, dt (B,H); x (B,H,hd);
+    B_, C_ (B,G,N).  Returns (the stack, y (B,H,hd) without the D skip)."""
+    rep = ssm.shape[2] // B_.shape[1]
+    Brep = jnp.repeat(B_, rep, axis=1)                  # (B,H,N)
+    Crep = jnp.repeat(C_, rep, axis=1)
+    state = jax.lax.dynamic_index_in_dim(ssm, layer, 0, keepdims=False)
+    state = state * da[..., None, None] + jnp.einsum(
+        "bh,bhd,bhn->bhdn", dt, x, Brep)
+    y = jnp.einsum("bhdn,bhn->bhd", state, Crep)
+    return jax.lax.dynamic_update_index_in_dim(ssm, state, layer, 0), y
+
+
+def _fuses_state_step(hd: int, n: int) -> bool:
+    """Whether the decode's state step runs through the Pallas kernel
+    (``kernels/ssm_step.py``): where kernels compile (a TPU), at widths that
+    tile it, and with no sharding in force, since GSPMD cannot partition a
+    Mosaic kernel.  Elsewhere the ``jax.numpy`` step runs."""
+    from repro.sharding.context import named_specs_installed
+
+    return (not kernel_backend.interpret_for() and hd % 8 == 0 and n % 128 == 0
+            and not named_specs_installed()
+            and jax.sharding.get_abstract_mesh().empty)
+
+
+# the tallies ``fused_state_steps`` has open in this context
+_TALLIES: contextvars.ContextVar = contextvars.ContextVar(
+    "fused_state_step_tallies", default=())
+
+
+@contextlib.contextmanager
+def fused_state_steps():
+    """While a decode traces, count the Mamba layers whose state step runs
+    through the kernel: yields a list whose entries' first items sum to it
+    (one entry a traced step, worth its stack's periods)."""
+    entries: list = []
+    token = _TALLIES.set(_TALLIES.get() + (entries,))
+    try:
+        yield entries
+    finally:
+        _TALLIES.reset(token)
+
+
+def _kernel_state_step(ssm, layer, da, dt, x, B_, C_):
+    """``_state_step`` through the kernel, in place.  The kernel steps one
+    decode's stack: a decode mapped by ``jax.vmap`` (``serve/engine.py``)
+    takes the ``jax.numpy`` step instead, and the tally forgets its
+    layers."""
+    from repro.kernels import ops as kernel_ops
+
+    layers = [ssm.shape[0]]             # this position's layer of each period
+    for entries in _TALLIES.get():
+        entries.append(layers)
+
+    @jax.custom_batching.custom_vmap
+    def step(*args):
+        return kernel_ops.ssm_state_step(*args)
+
+    @step.def_vmap
+    def _batched(axis_size, in_batched, *args):
+        layers[0] = 0
+        axes = [0 if b else None for b in in_batched]
+        return jax.vmap(_state_step, in_axes=axes)(*args), (True, True)
+
+    return step(ssm, layer, da, dt, x, B_, C_)

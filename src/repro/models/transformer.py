@@ -376,12 +376,8 @@ def _apply_block_decode(bp, cfg: ModelConfig, kind: str, use_moe: bool, x, cache
     ``layer``, in place. Returns (x, caches)."""
     h = rmsnorm(bp["norm1"], x, cfg.norm_eps)
     if kind == MAMBA:
-        lcache = jax.tree_util.tree_map(
-            lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False), caches)
-        h, new = mamba_lib.mamba_decode(bp["mamba"], h, lcache, cfg.mamba, cfg.d_model)
-        caches = jax.tree_util.tree_map(
-            lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, layer, 0),
-            caches, new)
+        h, caches = mamba_lib.mamba_decode(
+            bp["mamba"], h, caches, layer, cfg.mamba, cfg.d_model)
     else:
         h, caches = attn_lib.attention_decode(
             bp["attn"], h, caches, pos, layer, cfg_attn=_attn_cfg(cfg, kind))

@@ -259,7 +259,8 @@ class MetricsRegistry:
         lands as ``serve/*`` gauges next to the pool's ``serve/pool/*``
         counters, so the obs report covers the serving plane."""
         for key in ("admitted", "completed", "decode_steps", "prefills",
-                    "tokens_out", "ssm_state_bytes", "kv_cache_bytes"):
+                    "tokens_out", "ssm_state_bytes", "kv_cache_bytes",
+                    "ssm_fused_layers"):
             self.gauge(f"serve/{key}").set(float(getattr(stats, key)),
                                            step=step)
 

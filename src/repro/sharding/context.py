@@ -52,6 +52,10 @@ def set_named_specs(specs: Optional[dict]) -> None:
     _NAMED_SPECS = specs or {}
 
 
+def named_specs_installed() -> bool:
+    return bool(_NAMED_SPECS)
+
+
 def constrain_named(name: str, x):
     s = _NAMED_SPECS.get(name)
     if s is None:

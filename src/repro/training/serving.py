@@ -33,6 +33,7 @@ recorder when it is on and written into the profiler's trace while a
                                 ssm_state_bytes, kv_cache_bytes)
         serve/fetch             the host's wait for the prefill's tokens
       serve/decode              staging the tokens and dispatching the step
+                                (tags live, ssm_fused_layers)
       serve/fetch               the host's wait for the sampled tokens
       serve/emit                appending tokens, stop tests, retirements
 
@@ -51,6 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import MAMBA
+from repro.models.mamba import fused_state_steps
 from repro.obs import trace as obs_trace
 
 
@@ -76,6 +78,9 @@ class ServeStats:
     # tail) and attention layers' K/V, in bytes
     ssm_state_bytes: int = 0
     kv_cache_bytes: int = 0
+    # Mamba layers whose state step the decode program runs through the
+    # Pallas kernel (counted when it traces; 0 where the jnp step runs)
+    ssm_fused_layers: int = 0
 
 
 def cache_bytes(cache) -> tuple:
@@ -123,7 +128,10 @@ class ContinuousBatcher:
             return models.prefill(p, self.cfg, b, cache_len=self.max_len)
 
         def decode(p, t, c):
-            return models.decode_step(p, self.cfg, t, c)
+            with fused_state_steps() as fused:
+                out = models.decode_step(p, self.cfg, t, c)
+            self.stats.ssm_fused_layers = sum(n for n, in fused)
+            return out
 
         self._prefill = jax.jit(prefill)
         self._decode = jax.jit(decode,
@@ -229,9 +237,10 @@ class ContinuousBatcher:
                     if r is not None and not r.done]
             if not live or self.cache is None:
                 return 0
-            with obs_trace.span("serve/decode", live=len(live)):
+            with obs_trace.span("serve/decode", live=len(live)) as sp:
                 logits, self.cache = self._model_decode(
                     jnp.asarray(self.next_tok))
+                sp.tag(ssm_fused_layers=self.stats.ssm_fused_layers)
             nxt = jnp.argmax(logits[:, -1, :self.cfg.vocab_size], -1)
             with obs_trace.span("serve/fetch"):
                 nxt = np.asarray(nxt)

@@ -210,3 +210,70 @@ def test_donated_cache_serves_the_same_tokens(model):
         assert cb.stats.completed == 4
         served.append([list(r.generated) for r in reqs])
     assert served[0] == served[1]
+
+
+@pytest.fixture(scope="module")
+def kernel_model():
+    """The tiny hybrid at widths the Mamba state-step kernel tiles: 16 SSD
+    heads of 8 in 2 groups, d_state 128."""
+    c = dict(tiny_config(), mamba_n_heads=16, mamba_d_head=8,
+             mamba_d_state=128, mamba_n_groups=2)
+    sh = granite_hybrid.Shapes.from_config(c)
+    cfg = replace(model_config(c), dtype="float32")
+    tree = jax.jit(partial(granite_hybrid.bench_program_tree, sh))(
+        jax.random.PRNGKey(11))
+    return sh, cfg, granite_hybrid.as_f32(tree)
+
+
+def test_state_step_kernel_serves_the_decode(kernel_model, monkeypatch):
+    """Prefill, then 12 decode steps with each Mamba layer's state step in
+    the Pallas kernel (interpreted) give the logits of the jax.numpy step.
+    The two sum the readout over d_state in their own orders: they read
+    0.9e-6 to 1.8e-6 of the largest logit apart (2 seeds of weights, 3 of
+    tokens), and the bound is a third of ``TOL``.  The batcher counts the
+    layers the kernel serves: none on the CPU, all 9 when it engages."""
+    from repro.models import mamba
+
+    sh, cfg, params = kernel_model
+    toks = tokens_for(sh, 16, seed=3)
+    want = served_logits(cfg, params, toks, 16)
+    stats = ContinuousBatcher(cfg, params, n_slots=2, max_len=32).run()
+    assert stats.ssm_fused_layers == 0
+    monkeypatch.setattr(mamba, "_fuses_state_step", lambda hd, n: True)
+    got = served_logits(cfg, params, toks, 16)
+    assert gap(got, want) <= TOL / 3
+    cb = ContinuousBatcher(cfg, params, n_slots=2, max_len=32)
+    cb.submit(Request(rid=0, prompt=np.asarray(toks[0, :6]), max_new=2))
+    assert cb.run().ssm_fused_layers == 9
+
+
+def test_vmapped_decode_takes_the_jnp_state_step(kernel_model, monkeypatch):
+    """Mapped over slots by ``jax.vmap`` (as ``serve/engine.py`` decodes),
+    the kernel's state step falls back to the jax.numpy step: the logits
+    are those of the unfused program, bit for bit, and no layer counts
+    as fused."""
+    from repro.models import mamba
+
+    sh, cfg, params = kernel_model
+    toks = tokens_for(sh, 8, seed=4)
+    _, cache = prefill(params, cfg, {"tokens": toks[:, :8]}, cache_len=12)
+
+    def slot_major(path, a):                 # K/V are sequence-major
+        axis = 2 if path[-1].key in ("k", "v") else 1
+        return a if a.ndim == 0 else jnp.moveaxis(
+            jnp.expand_dims(a, axis + 1), axis, 0)
+
+    per_slot = jax.tree_util.tree_map_with_path(slot_major, cache)
+
+    def decode(t, c):
+        return decode_step(params, cfg, t[None], c)[0]
+
+    mapped = jax.jit(jax.vmap(decode, in_axes=(0, {"layers": 0, "pos": None})))
+    want = np.asarray(mapped(toks[:, 8:9], per_slot))
+    monkeypatch.setattr(mamba, "_fuses_state_step", lambda hd, n: True)
+    with mamba.fused_state_steps() as fused:
+        got = np.asarray(jax.jit(jax.vmap(
+            decode, in_axes=(0, {"layers": 0, "pos": None})))(
+                toks[:, 8:9], per_slot))
+    assert fused and sum(n for n, in fused) == 0
+    np.testing.assert_array_equal(got, want)

@@ -2,7 +2,8 @@
 
 Nothing runs: each wrapper in ``kernels/ops.py`` is lowered and compiled by
 the TPU compiler for a chip that is described, not attached, at the size of
-one h2o-danube-1.8b MLP leaf (d_model x d_ff = 2560 x 6912).  A kernel the
+one h2o-danube-1.8b MLP leaf (d_model x d_ff = 2560 x 6912; the Mamba state
+step at mamba2-2.7b's widths).  A kernel the
 chip's compiler would refuse (misaligned VMEM slices, unsupported
 reductions, too much VMEM) fails here, and every case asserts that the
 compiled program really holds the kernel (``tpu_custom_call``) rather than an
@@ -13,7 +14,8 @@ The serving decode step is compiled the same way, at Qwen1.5-4B's widths,
 and its optimized HLO is read for copies of the KV cache: the step must
 write its token into the stacked cache in place.  granite-4.0-h-micro's
 decode, as the batcher builds it, must alias its whole cache when the
-batcher donates it, and copy no SSM state then.
+batcher donates it, copy no SSM state then, and step each Mamba layer's
+state in one kernel pass.
 
 The topology is described only inside the module fixture: loading the TPU
 library at import or collection time would make test workers disagree on
@@ -89,6 +91,15 @@ def _case(name, s):
         return ops.unpack_bits, (_sds((-(-D // 32),), jnp.uint32, s),), {"d": D}
     if name == "prune_nm":
         return ops.prune_nm, (w, w), {}
+    if name == "ssm_state_step":
+        # mamba2-2.7b's widths (80 heads of 64, d_state 128): blocks of 40
+        # heads; a stack of 4 layers at 8 slots
+        p_, b_, h_, hd, n = 4, 8, 80, 64, 128
+        return ops.ssm_state_step, (
+            _sds((p_, b_, h_, hd, n), jnp.float32, s), _sds((), jnp.int32, s),
+            _sds((b_, h_), jnp.float32, s), _sds((b_, h_), jnp.float32, s),
+            _sds((b_, h_, hd), jnp.float32, s), _sds((b_, 1, n), jnp.float32, s),
+            _sds((b_, 1, n), jnp.float32, s)), {}
     mode = name.removeprefix("prune_scored_")
     x = _sds((CALIB_TOKENS, D_IN), jnp.float32, s)
     return ops.prune_scored, (w, x), {"mode": mode}
@@ -96,7 +107,8 @@ def _case(name, s):
 
 KERNELS = ("quantize_dequantize", "quantize_pack", "stream_quantize_pack",
            "unpack_dequantize", "pack_bits", "unpack_bits", "prune_nm",
-           "prune_scored_wanda", "prune_scored_ria", "prune_scored_symwanda")
+           "prune_scored_wanda", "prune_scored_ria", "prune_scored_symwanda",
+           "ssm_state_step")
 
 
 @pytest.mark.parametrize("name", KERNELS)
@@ -233,17 +245,21 @@ def test_decode_writes_the_cache_in_place(decode_hlo):
 
 
 # granite-4.0-h-micro whole (36 Mamba-2 layers, 4 attention) at 32 slots and
-# a 1536-position cache, as the hybrid serving cell runs it
+# a 1536-position cache, as the hybrid serving cell runs it.  The program
+# asks the backend whether kernels compile; the test answers as a TPU does,
+# so the decode takes the Mamba state-step kernel as it does on the chip.
 HYBRID_SLOTS, HYBRID_CACHE_LEN = 32, 1536
+_hybrid_decodes: dict = {}
 
 
-@pytest.mark.parametrize("donate", [False, True])
-def test_donated_decode_writes_the_ssm_state_in_place(one_chip, donate):
-    """``ContinuousBatcher(donate_cache=True)``'s decode program aliases the
-    whole cache to its output and copies no stacked SSM state; undonated,
-    each stack is copied before the step writes it."""
+def _hybrid_decode(one_chip, donate):
+    """(compiled decode program, cache shapes, ServeStats) of
+    ``ContinuousBatcher``'s granite decode, compiled once per arm."""
+    if donate in _hybrid_decodes:
+        return _hybrid_decodes[donate]
     from repro import models
     from repro.configs import get_config
+    from repro.kernels import backend
     from repro.training.serving import ContinuousBatcher
 
     cfg = get_config("granite-4.0-h-micro")
@@ -254,12 +270,29 @@ def test_donated_decode_writes_the_ssm_state_in_place(one_chip, donate):
                  lambda a: _sds(a.shape, a.dtype, one_chip))
     batcher = ContinuousBatcher(cfg, None, n_slots=HYBRID_SLOTS,
                                 max_len=HYBRID_CACHE_LEN, donate_cache=donate)
-    compiled = batcher._decode.lower(
-        on(params), _sds((HYBRID_SLOTS, 1), jnp.int32, one_chip),
-        on(cache)).compile()
-    states = [a for path, a in
-              jax.tree_util.tree_flatten_with_path(cache["layers"])[0]
-              if path[-1].key == "ssm"]
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(backend, "interpret_for",
+                   lambda backend_name=None: interpret_for("tpu"))
+        compiled = batcher._decode.lower(
+            on(params), _sds((HYBRID_SLOTS, 1), jnp.int32, one_chip),
+            on(cache)).compile()
+    _hybrid_decodes[donate] = compiled, cache, batcher.stats
+    return _hybrid_decodes[donate]
+
+
+def _ssm_states(cache):
+    return [a for path, a in
+            jax.tree_util.tree_flatten_with_path(cache["layers"])[0]
+            if path[-1].key == "ssm"]
+
+
+@pytest.mark.parametrize("donate", [False, True])
+def test_donated_decode_writes_the_ssm_state_in_place(one_chip, donate):
+    """``ContinuousBatcher(donate_cache=True)``'s decode program aliases the
+    whole cache to its output and copies no stacked SSM state; undonated,
+    each stack is copied before the step writes it."""
+    compiled, cache, _ = _hybrid_decode(one_chip, donate)
+    states = _ssm_states(cache)
     state_copies = [name for lines in _computations(compiled.as_text()).values()
                     for name, n, opcode, _ in _instructions(lines)
                     if opcode == "copy" and n == states[0].size]
@@ -270,3 +303,36 @@ def test_donated_decode_writes_the_ssm_state_in_place(one_chip, donate):
         assert aliased >= cache_bytes and not state_copies, state_copies
     else:
         assert aliased == 0 and len(state_copies) == len(states)
+
+
+_CUSTOM_CALL = re.compile(r"^\s*(?:ROOT )?%(\S+) = .* custom-call\(")
+_FUSION = re.compile(r"^\s*(?:ROOT )?%(\S+) = (.*?) fusion\(")
+
+
+def test_decode_steps_each_ssm_state_in_one_pass(one_chip):
+    """The donated decode runs each Mamba layer's state step as one kernel
+    call on the stacked state (one a Mamba position of the period, in the
+    layer loop): no fusion writes a layer's state to a temporary, no
+    dynamic-update-slice writes one into its stack, and the whole cache
+    is still aliased.  The batcher counts the 36 layers the kernel serves."""
+    compiled, cache, stats = _hybrid_decode(one_chip, True)
+    hlo = compiled.as_text()
+    comps = _computations(hlo)
+    bodies = set(re.findall(r"body=%([\w.\-]+)", hlo))
+    states = _ssm_states(cache)
+    layer_state = "f32[%s]" % ",".join(map(str, states[0].shape[1:]))
+    kernels = [m.group(1) for comp in bodies for line in comps[comp]
+               if (m := _CUSTOM_CALL.match(line)) and "tpu_custom_call" in line
+               and m.group(1).startswith("ssm_state_step")]
+    assert len(kernels) == len(states), kernels
+    temporaries = [m.group(1) for lines in comps.values() for line in lines
+                   if (m := _FUSION.match(line)) and layer_state in m.group(2)]
+    assert not temporaries, temporaries
+    state_writes = [name for lines in comps.values()
+                    for name, n, opcode, _ in _instructions(lines)
+                    if opcode == "dynamic-update-slice" and n == states[0].size]
+    assert not state_writes, state_writes
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree_util.tree_leaves(cache["layers"]))
+    assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
+    assert stats.ssm_fused_layers == 36
