@@ -1,8 +1,9 @@
 """Benchmark entry: one function per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV rows.  The §Roofline harness
-(benchmarks/roofline.py) and the multi-pod dry-run (repro.launch.dryrun) are
-separate long-running entries — this file covers the paper-table benchmarks.
+Prints ``name,us_per_call,derived`` CSV rows.  The multi-pod dry-run
+(repro.launch.dryrun) is a separate long-running entry, and the chip
+benchmark is ``benchmarks/chip/run.py`` — this file covers the paper-table
+benchmarks.
 
 The comm, hier, faults, cohort and serve rows are additionally written to
 ``BENCH_comm.json`` / ``BENCH_hier.json`` / ``BENCH_faults.json`` /

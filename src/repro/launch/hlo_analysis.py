@@ -12,8 +12,8 @@ bytes each collective moves per participating device:
     all-to-all          operand bytes x (group-1)/group
     collective-permute  operand bytes
 
-Payload bytes are per-device; multiplying by the link count is the roofline
-model's job (launch/roofline.py).
+Payload bytes are per-device; multiplying by the link count is left to the
+caller.
 """
 from __future__ import annotations
 

@@ -32,8 +32,3 @@ def make_host_mesh(model: int = 1):
         data, model = n, 1
     return make_mesh((data, model), ("data", "model"))
 
-
-# TPU v5e hardware constants used by the roofline analysis
-PEAK_FLOPS_BF16 = 197e12        # per chip
-HBM_BW = 819e9                  # bytes/s per chip
-ICI_BW = 50e9                   # bytes/s per link

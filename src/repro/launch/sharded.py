@@ -21,7 +21,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs.base import InputShape, SyncConfig, TrainConfig
 from repro.launch.specs import input_specs
 from repro.models import init_params
-from repro.sharding import rules
 from repro.sharding.context import set_grad_specs, set_moe_specs
 from repro.sharding.rules import (
     batch_specs, cache_pspecs, data_axes, maybe_axis, param_specs)
@@ -55,11 +54,7 @@ def _trace_hooks(act=None, grads=None, moe=None):
 def _moe_specs(cfg, mesh, impl: str, **extra):
     if cfg.moe is None:
         return None
-    from repro.sharding.context import get_moe_gather_quant, get_moe_impl_override
-
-    return {"impl": get_moe_impl_override() or impl, "mesh": mesh,
-            "data_axes": data_axes(mesh),
-            "gather_quant": get_moe_gather_quant(), **extra}
+    return {"impl": impl, "mesh": mesh, "data_axes": data_axes(mesh), **extra}
 
 
 def mesh_groups(mesh) -> tuple:
@@ -71,12 +66,12 @@ def mesh_groups(mesh) -> tuple:
     return n_groups, mesh.shape.get("pod", 1)
 
 
-def auto_grad_accum(cfg, shape, n_data: int, width_shards: int = 16) -> int:
+def auto_grad_accum(cfg, shape, n_data: int) -> int:
     """Microbatch count so remat residuals + logits fit HBM: scale with the
     per-device token load and residual width."""
     local_batch = max(1, shape.global_batch // n_data)
     resid_gb = (cfg.num_layers * local_batch * shape.seq_len * cfg.d_model * 2
-                / width_shards / 1e9)  # model-sharded bf16 stack
+                / 16 / 1e9)  # bf16 stack sharded 16 ways over 'model'
     accum = 1
     while resid_gb / accum > 1.0 and accum < local_batch:
         accum *= 2
@@ -120,12 +115,9 @@ def lower_train_step(cfg, mesh, tc: TrainConfig) -> ShardedTrainStep:
         act = NamedSharding(mesh, P("data", None, "model") if mode == "hier"
                             else P(None, None, "model"))
     elif mode == "dense":
-        fsdp_ax = daxes + ("model",) if rules.NO_TP else daxes
         pspecs = param_specs(state_abs.params, mesh, extra_leading=1,
-                             fsdp_axes=fsdp_ax)
-        # NO_TP: pure data parallel, batch over ALL axes, no model-dim sharding
-        act = NamedSharding(mesh, P(daxes + ("model",), None, None) if rules.NO_TP
-                            else P(dax, None, "model"))
+                             fsdp_axes=daxes)
+        act = NamedSharding(mesh, P(dax, None, "model"))
     else:  # efbv family: per-group grads via vmap — batch dim is mapped
         pspecs = param_specs(state_abs.params, mesh, extra_leading=1,
                              fsdp_axes=daxes)
@@ -182,9 +174,6 @@ def build_train_lowering(cfg, mesh, shape, sync_mode="dense", compressor="qsgd",
         n_groups, _ = mesh_groups(mesh)
         if sync_mode != "dense":
             grad_accum = 1
-        elif rules.NO_TP:
-            grad_accum = auto_grad_accum(
-                cfg, shape, n_groups * mesh.shape["model"], width_shards=1)
         else:
             grad_accum = auto_grad_accum(cfg, shape, n_groups)
     tc = TrainConfig(model=cfg, seq_len=shape.seq_len, global_batch=shape.global_batch,

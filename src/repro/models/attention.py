@@ -1,9 +1,9 @@
 """GQA attention with global / sliding-window / chunked-local variants.
 
-Three entry points:
-  * ``attention_train``   — full-sequence causal attention, blockwise
-    (flash-style) over KV so S=32k never materializes an S x S score matrix.
-  * ``attention_prefill`` — same math, also returns the KV cache.
+Two entry points:
+  * ``attention``         — full-sequence causal attention, blockwise
+    (flash-style) over KV so S=32k never materializes an S x S score matrix;
+    also returns the sequence's K/V, which prefill keeps as its cache.
   * ``attention_decode``  — one query token against a cache (full, ring-buffer
     for SWA/chunked, per the layer kind).
 
@@ -71,16 +71,9 @@ def _block_mask(q_idx, k_idx, kind: str, window: int, chunk: int):
     return jnp.where(ok, 0.0, NEG_INF).astype(jnp.float32)
 
 
-# Tile sizes: (BLOCK_Q x BLOCK_K) transient score tiles. Overridable by the
-# dry-run costing harness (which exploits linearity in the block size).
+# Tile sizes: (BLOCK_Q x BLOCK_K) transient score tiles.
 BLOCK_Q = 512
 BLOCK_K = 1024
-
-# Banded flash (perf option, §Perf hillclimb): SWA/chunked layers only visit
-# the KV blocks their window/chunk can reach instead of all of them.  The
-# baseline (False) is the paper-faithful full sweep with masking — identical
-# numerics, O(S^2) work; banded cuts attention work to O(S * window).
-BANDED = False
 
 
 def _flash_attention(q, k, v, kind: str, window: int, chunk: int,
@@ -137,12 +130,6 @@ def _flash_attention(q, k, v, kind: str, window: int, chunk: int,
             "bqkgn,bnkh->bqkgh", p, v_blk.astype(jnp.float32))
         return (m_new, l_new, acc, q_tile, qi), None
 
-    # banded mode: number of KV blocks any query tile can actually reach
-    banded = BANDED and kind in ("attn_swa", "attn_chunk")
-    if banded:
-        reach = window if kind == "attn_swa" else chunk
-        R = min(nk, -(-reach // bk) + (2 if bq > 1 else 1))
-
     kbs = kb.swapaxes(0, 1)  # (nk, B, bk, KV, hd)
     vbs = vb.swapaxes(0, 1)
 
@@ -151,27 +138,8 @@ def _flash_attention(q, k, v, kind: str, window: int, chunk: int,
         m0 = jnp.full((B, bq, KV, G), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, bq, KV, G), jnp.float32)
         a0 = jnp.zeros((B, bq, KV, G, hd), jnp.float32)
-        if banded:
-            # visit blocks qb_end, qb_end-1, ..., down to the window floor
-            qb_end = (qi * bq + bq - 1 + q_offset) // bk
-
-            def band_body(carry, r):
-                blk = qb_end - r
-                valid = blk >= 0
-                blk_c = jnp.clip(blk, 0, nk - 1)
-                k_blk = jax.lax.dynamic_index_in_dim(kbs, blk_c, 0, keepdims=False)
-                v_blk = jax.lax.dynamic_index_in_dim(vbs, blk_c, 0, keepdims=False)
-                new_carry, _ = kv_body(carry, (k_blk, v_blk, blk_c))
-                # invalid (negative) blocks contribute nothing
-                merged = jax.tree_util.tree_map(
-                    lambda new, old: jnp.where(valid, new, old), new_carry, carry)
-                return merged, None
-
-            (m, l, acc, _, _), _ = jax.lax.scan(
-                band_body, (m0, l0, a0, q_tile, qi), jnp.arange(R))
-        else:
-            (m, l, acc, _, _), _ = jax.lax.scan(
-                kv_body, (m0, l0, a0, q_tile, qi), (kbs, vbs, jnp.arange(nk)))
+        (m, l, acc, _, _), _ = jax.lax.scan(
+            kv_body, (m0, l0, a0, q_tile, qi), (kbs, vbs, jnp.arange(nk)))
         out = acc / jnp.maximum(l[..., None], 1e-30)
         return None, out
 
@@ -182,24 +150,11 @@ def _flash_attention(q, k, v, kind: str, window: int, chunk: int,
     return out.astype(q.dtype)
 
 
-def attention_train(params, x, *, cfg_attn: dict, positions=None):
-    """cfg_attn keys: num_heads num_kv_heads head_dim kind window chunk
-    qk_norm use_rope rope_theta scale (None: 1/sqrt(head_dim))."""
-    B, S, _ = x.shape
-    if positions is None:
-        positions = jnp.arange(S)[None, :]
-    q, k, v = _project_qkv(params, x, cfg_attn["num_heads"], cfg_attn["num_kv_heads"],
-                           cfg_attn["head_dim"], cfg_attn["qk_norm"], cfg_attn["use_rope"],
-                           positions, cfg_attn["rope_theta"])
-    out = _flash_attention(q, k, v, cfg_attn["kind"], cfg_attn["window"], cfg_attn["chunk"],
-                           scale=cfg_attn["scale"])
-    return out.reshape(B, S, -1) @ params["wo"]
-
-
-def attention_prefill(params, x, *, cfg_attn: dict):
-    """Returns (output, cache{k,v}). Cache keeps full K/V; for SWA/chunked
-    layers the decode path only reads the live window (ring semantics are
-    realized at decode time via position masking, keeping shapes static)."""
+def attention(params, x, *, cfg_attn: dict):
+    """Causal attention over the whole sequence x (B,S,D).  cfg_attn keys:
+    num_heads num_kv_heads head_dim kind window chunk qk_norm use_rope
+    rope_theta scale (None: 1/sqrt(head_dim)).  Returns (output, {k, v}),
+    the full (B,S,KV,hd) K/V that prefill turns into the decode cache."""
     B, S, _ = x.shape
     positions = jnp.arange(S)[None, :]
     q, k, v = _project_qkv(params, x, cfg_attn["num_heads"], cfg_attn["num_kv_heads"],

@@ -130,30 +130,23 @@ def _ssd_chunked(x, dt, A, B_, C_, D, chunk: int):
     return y, final_state
 
 
-def mamba_train(params, u, cfg, d_model: int) -> jax.Array:
-    y, _ = mamba_forward(params, u, cfg, d_model)
-    return y
-
-
-def mamba_forward(params, u, cfg, d_model: int, return_cache: bool = False):
+def mamba_forward(params, u, cfg, d_model: int):
+    """The layer over a whole sequence u (B,S,d_model).  Returns (out, the
+    decode cache it leaves: {ssm final state, conv tail of the last
+    d_conv - 1 raw conv inputs})."""
     dims = mamba_dims(d_model, cfg)
     di, H, G, N = dims["d_inner"], dims["n_heads"], cfg.n_groups, cfg.d_state
     hd = cfg.head_dim
     Bsz, S, _ = u.shape
 
-    from repro.sharding.context import constrain_named
-
     z, xBC_raw, dt = _split_proj(params, u, cfg, dims)
     with annotate("mamba/conv"):
         xBC = _causal_conv(params, xBC_raw, cfg)
     x, B_, C_ = jnp.split(xBC, [di, di + G * N], axis=-1)
-    # optional SSD head sharding (perf variant): keeps the (B,K,Q,Q,H)
-    # intra-chunk tensors model-sharded over heads instead of replicated
-    x = constrain_named("ssd_x", x.reshape(Bsz, S, H, hd))
+    x = x.reshape(Bsz, S, H, hd)
     B_ = B_.reshape(Bsz, S, G, N)
     C_ = C_.reshape(Bsz, S, G, N)
-    dt = constrain_named("ssd_dt",
-                         jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"]))
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])
     A = -jnp.exp(params["a_log"])
 
     chunk = min(cfg.chunk_size, S)
@@ -170,14 +163,11 @@ def mamba_forward(params, u, cfg, d_model: int, return_cache: bool = False):
     y = y.reshape(Bsz, S, di).astype(u.dtype)
     y = rmsnorm(params["norm"], y * jax.nn.silu(z))
     out = y @ params["out_proj"]
-    if return_cache:
-        # decode-compatible cache: final SSM state + last (d_conv-1) raw conv inputs
-        K = cfg.d_conv
-        tail = xBC_raw[:, -(K - 1):, :]
-        if S < K - 1:
-            tail = jnp.pad(xBC_raw, ((0, 0), (K - 1 - S, 0), (0, 0)))
-        return out, {"ssm": state, "conv": tail}
-    return out, state
+    K = cfg.d_conv
+    tail = xBC_raw[:, -(K - 1):, :]
+    if S < K - 1:
+        tail = jnp.pad(xBC_raw, ((0, 0), (K - 1 - S, 0), (0, 0)))
+    return out, {"ssm": state, "conv": tail}
 
 
 def mamba_cache_spec(d_model: int, cfg, batch: int, dtype):
@@ -247,10 +237,7 @@ def _fuses_state_step(hd: int, n: int) -> bool:
     (``kernels/ssm_step.py``): where kernels compile (a TPU), at widths that
     tile it, and with no sharding in force, since GSPMD cannot partition a
     Mosaic kernel.  Elsewhere the ``jax.numpy`` step runs."""
-    from repro.sharding.context import named_specs_installed
-
     return (not kernel_backend.interpret_for() and hd % 8 == 0 and n % 128 == 0
-            and not named_specs_installed()
             and jax.sharding.get_abstract_mesh().empty)
 
 

@@ -31,9 +31,7 @@ def moe_apply(params: dict, x: jax.Array, **kw) -> Tuple[jax.Array, jax.Array]:
                                 data_axes=specs["data_axes"], **kw)
     if specs and specs.get("impl") == "shardmap":
         return moe_ffn_shardmap(params, x, mesh=specs["mesh"],
-                                data_axes=specs["data_axes"],
-                                gather_quant=specs.get("gather_quant", False),
-                                **kw)
+                                data_axes=specs["data_axes"], **kw)
     return moe_ffn(params, x, **kw)
 
 
@@ -245,11 +243,7 @@ def moe_ffn_alltoall(params: dict, x: jax.Array, *, num_experts: int, top_k: int
 def moe_ffn_shardmap(params: dict, x: jax.Array, *, num_experts: int, top_k: int,
                      capacity_factor: float, act: str, gated: bool,
                      shared_expert: bool, mesh, data_axes,
-                     model_axis: str = "model",
-                     gather_quant: bool = False) -> Tuple[jax.Array, jax.Array]:
-    """``gather_quant`` (§Perf variant): the entry token replication over
-    'model' moves int8 payloads (blockwise absmax, one scale per token) and
-    the exit psum runs in bf16 — ~2x less MoE collective traffic."""
+                     model_axis: str = "model") -> Tuple[jax.Array, jax.Array]:
     from jax.sharding import PartitionSpec as P
 
     B, S, d = x.shape
@@ -259,32 +253,10 @@ def moe_ffn_shardmap(params: dict, x: jax.Array, *, num_experts: int, top_k: int
     e_per = max(1, E // n_model)
 
     dax = data_axes if isinstance(data_axes, tuple) else (data_axes,)
-    n_data = 1
-    for a in dax:
-        n_data *= mesh.shape[a]
     dspec = dax if len(dax) > 1 else dax[0]
 
-    def local_gather(xt_shard):
-        """(T_loc, d/n_model) my d-shard -> (T_loc, d) full, int8 on the wire."""
-        if not gather_quant:
-            return jax.lax.all_gather(xt_shard, model_axis, axis=1, tiled=True)
-        scale = jnp.max(jnp.abs(xt_shard.astype(jnp.float32)), axis=1,
-                        keepdims=True) / 127.0
-        scale = jnp.where(scale == 0, 1.0, scale)
-        q = jnp.clip(jnp.round(xt_shard.astype(jnp.float32) / scale),
-                     -127, 127).astype(jnp.int8)
-        qg = jax.lax.all_gather(q, model_axis, axis=1, tiled=True)
-        sg = jax.lax.all_gather(scale, model_axis, axis=1, tiled=True)
-        # dequant shard-by-shard: scales repeat per d-shard block
-        dsh = xt_shard.shape[1]
-        qg = qg.reshape(qg.shape[0], n_model, dsh)
-        out = qg.astype(jnp.float32) * sg[:, :, None]
-        return out.reshape(qg.shape[0], n_model * dsh).astype(xt_shard.dtype)
-
     def local_fn(xt, router, w_in, w_gate, w_out):
-        # xt: model-replicated (T_loc, d), or my d-shard when gather_quant
-        if gather_quant:
-            xt = local_gather(xt)
+        # xt: model-replicated (T_loc, d)
         T_loc = xt.shape[0]
         C = max(1, int(T_loc * K * capacity_factor / E))
         logits = xt.astype(jnp.float32) @ router               # (T_loc, E)
@@ -325,19 +297,15 @@ def moe_ffn_shardmap(params: dict, x: jax.Array, *, num_experts: int, top_k: int
             wk = (gate_w.reshape(-1) * mine)[:, None]
             contrib = y[jnp.minimum(rank, C - 1)] * wk         # (T_loc*K, d)
             combined = combined.at[token_of].add(contrib.astype(jnp.float32))
-        if gather_quant:
-            combined = jax.lax.psum(combined.astype(jnp.bfloat16), model_axis)
-        else:
-            combined = jax.lax.psum(combined, model_axis)
+        combined = jax.lax.psum(combined, model_axis)
         # aux is identical across model shards (same routing math) but is a
         # LOCAL-token statistic along the data axes — average it
         aux = jax.lax.pmean(aux, dax if len(dax) > 1 else dax[0])
         return combined.astype(x.dtype), aux
 
-    in_tok_spec = P(dspec, model_axis) if gather_quant else P(dspec, None)
     local = jax.shard_map(
         local_fn, mesh=mesh,
-        in_specs=(in_tok_spec, P(), P(model_axis, None, None),
+        in_specs=(P(dspec, None), P(), P(model_axis, None, None),
                   P(model_axis, None, None), P(model_axis, None, None)),
         out_specs=(P(dspec, None), P()),
         check_vma=False,

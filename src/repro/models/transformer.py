@@ -11,7 +11,6 @@ from __future__ import annotations
 
 import math
 from functools import partial
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +22,7 @@ from repro.models import moe as moe_lib
 from repro.models.layers import (
     _dense_init,
     cross_entropy_loss,
+    apply_rope,
     embed,
     init_embed,
     init_mlp,
@@ -167,25 +167,28 @@ def init_params(key, cfg: ModelConfig) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# Block application (shared by train / prefill / decode)
+# The block, which train, prefill and decode all apply
 # ---------------------------------------------------------------------------
-def _apply_block_train(bp, cfg: ModelConfig, kind: str, use_moe: bool, x,
-                       enc_out: Optional[jax.Array]):
+def _apply_block(bp, cfg: ModelConfig, kind: str, use_moe: bool, x, memory,
+                 mix, moe, state=None):
+    """One block: norm1, the mixer, residual, cross-attention to ``memory``
+    (with one), norm2, the MLP or MoE, residual.
+
+    What differs between the uses comes in as calls:
+    ``mix(bp, kind, h, state)`` runs the block's attention or Mamba layer
+    and returns (h, the layer's cache), and ``moe(params, h, **kw)`` returns
+    (h, router aux loss).  Returns (x, cache, aux)."""
     aux = jnp.zeros((), jnp.float32)
     h = rmsnorm(bp["norm1"], x, cfg.norm_eps)
-    if kind == MAMBA:
-        h = mamba_lib.mamba_train(bp["mamba"], h, cfg.mamba, cfg.d_model)
-    else:
-        h = attn_lib.attention_train(bp["attn"], h, cfg_attn=_attn_cfg(cfg, kind))
+    h, state = mix(bp, kind, h, state)
     x = x + _residual(cfg, h)
-    if cfg.cross_attn and enc_out is not None:
+    if cfg.cross_attn and memory is not None:
         h = rmsnorm(bp["norm_x"], x, cfg.norm_eps)
-        h = _cross_attention(bp["xattn"], h, enc_out, cfg)
-        x = x + h
+        x = x + _unmasked_attention(bp["xattn"], h, memory, cfg)
     if cfg.d_ff > 0:
         h = rmsnorm(bp["norm2"], x, cfg.norm_eps)
         if use_moe:
-            h, a = moe_lib.moe_apply(
+            h, a = moe(
                 bp["moe"], h, num_experts=cfg.moe.num_experts, top_k=cfg.moe.top_k,
                 capacity_factor=cfg.moe.capacity_factor, act=cfg.mlp_act,
                 gated=cfg.mlp_gated, shared_expert=cfg.moe.shared_expert)
@@ -193,26 +196,27 @@ def _apply_block_train(bp, cfg: ModelConfig, kind: str, use_moe: bool, x,
         else:
             h = mlp(bp["mlp"], h, act=cfg.mlp_act, gated=cfg.mlp_gated)
         x = x + _residual(cfg, h)
-    return x, aux
+    return x, state, aux
 
 
-def _cross_attention(params, x, memory, cfg: ModelConfig):
-    """Non-causal attention from decoder x (B,Sq,D) to encoder memory (B,Sk,De)."""
+def _unmasked_attention(params, x, memory, cfg: ModelConfig, rope: bool = False):
+    """Non-causal attention from x (B,Sq,D) to memory (B,Sk,De): the
+    decoder's cross-attention, or with ``memory`` x and ``rope`` the
+    encoder's self-attention.  It runs through the tiled flash attention: the
+    naive (B,H,Sq,Sk) score tensor costs 17 GB/chip per seamless encoder
+    layer at S=4k."""
     B, Sq, _ = x.shape
     Sk = memory.shape[1]
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     q = (x @ params["wq"]).reshape(B, Sq, H, hd)
     k = (memory @ params["wk"]).reshape(B, Sk, KV, hd)
     v = (memory @ params["wv"]).reshape(B, Sk, KV, hd)
-    out = _full_attention_nomask(q, k, v)
+    if rope:
+        pos = jnp.arange(Sq)[None, :]
+        q = apply_rope(q, pos, cfg.rope_theta)
+        k = apply_rope(k, pos, cfg.rope_theta)
+    out = attn_lib._flash_attention(q, k, v, "full", 0, 0)
     return out.reshape(B, Sq, H * hd) @ params["wo"]
-
-
-def _full_attention_nomask(q, k, v):
-    """Non-causal attention through the tiled flash kernel: the naive
-    (B,H,Sq,Sk) score tensor costs 17 GB/chip per seamless encoder layer at
-    S=4k — the flash path is numerically identical with O(bq*bk) transients."""
-    return attn_lib._flash_attention(q, k, v, "full", 0, 0)
 
 
 # ---------------------------------------------------------------------------
@@ -222,25 +226,6 @@ def _full_attention_nomask(q, k, v):
 # production mesh). No-op outside a mesh context.
 # ---------------------------------------------------------------------------
 _ACT_SPEC = None
-
-# Costing-harness switch: unroll the layer-period scan into a python loop so
-# HLO cost analysis (which counts while bodies ONCE, ignoring trip counts)
-# sees every period.  Only used with 1-2 period variant configs.
-UNROLL_SCAN = False
-
-
-def stack_scan(f, init, xs):
-    if not UNROLL_SCAN:
-        return jax.lax.scan(f, init, xs)
-    n = jax.tree_util.tree_leaves(xs)[0].shape[0]
-    carry, ys = init, []
-    for i in range(n):
-        xi = jax.tree_util.tree_map(lambda a: a[i], xs)
-        carry, y = f(carry, xi)
-        ys.append(y)
-    if ys and ys[0] is None:
-        return carry, None
-    return carry, jax.tree_util.tree_map(lambda *a: jnp.stack(a), *ys)
 
 
 def set_activation_sharding(spec) -> None:
@@ -268,72 +253,80 @@ def _remat_wrap(fn, remat: str):
 # ---------------------------------------------------------------------------
 def encode(params, cfg: ModelConfig, src_embeds: jax.Array, remat: str = "dots") -> jax.Array:
     enc = params["encoder"]
-    acfg = _attn_cfg(cfg, "attn")
-    acfg["use_rope"] = True
 
     def body(x, bp):
         h = rmsnorm(bp["norm1"], x, cfg.norm_eps)
-        h = _noncausal_self_attention(bp["attn"], h, acfg)
+        h = _unmasked_attention(bp["attn"], h, h, cfg, rope=True)
         x = x + h
         h = rmsnorm(bp["norm2"], x, cfg.norm_eps)
         x = x + mlp(bp["mlp"], h, act=cfg.mlp_act, gated=cfg.mlp_gated)
         return x, None
 
-    x, _ = stack_scan(_remat_wrap(body, remat), src_embeds, enc["blocks"])
+    x, _ = jax.lax.scan(_remat_wrap(body, remat), src_embeds, enc["blocks"])
     return rmsnorm(enc["final_norm"], x, cfg.norm_eps)
 
 
-def _noncausal_self_attention(params, x, acfg):
-    B, S, _ = x.shape
-    H, KV, hd = acfg["num_heads"], acfg["num_kv_heads"], acfg["head_dim"]
-    q = (x @ params["wq"]).reshape(B, S, H, hd)
-    k = (x @ params["wk"]).reshape(B, S, KV, hd)
-    v = (x @ params["wv"]).reshape(B, S, KV, hd)
-    from repro.models.layers import apply_rope
-    pos = jnp.arange(S)[None, :]
-    q = apply_rope(q, pos, acfg["rope_theta"])
-    k = apply_rope(k, pos, acfg["rope_theta"])
-    out = _full_attention_nomask(q, k, v)
-    return out.reshape(B, S, H * hd) @ params["wo"]
-
-
 # ---------------------------------------------------------------------------
-# Forward (train): returns (logits, aux_loss)
+# Whole sequences: train and prefill
 # ---------------------------------------------------------------------------
-def forward_train(params, cfg: ModelConfig, batch: dict, remat: str = "dots"):
-    P, n_periods, pos_kinds, pos_moe = period_info(cfg)
-    tokens = batch["tokens"]
-    # precomputed embeddings (grad-accum hoists the gather out of its scan —
-    # GSPMD's gather partitioning is unsound inside a while body)
+def _embed_inputs(params, cfg: ModelConfig, batch: dict, remat: str):
+    """The residual stream's input (B,S,D) and the encoder's memory (None
+    without an encoder): the token embedding, or ``inputs_embeds`` where
+    grad-accum hoisted the gather out of its scan (GSPMD's gather
+    partitioning is unsound inside a while body), scaled, with the
+    projected vision embeddings spliced over its first positions."""
     if "inputs_embeds" in batch:
         x = batch["inputs_embeds"]
     else:
-        x = embed(params["embed"], tokens)
+        x = embed(params["embed"], batch["tokens"])
     x = _scale_embeddings(cfg, x)
-
     if cfg.vision_tokens and "vision_embeds" in batch:
         vis = batch["vision_embeds"] @ params["vision_proj"]
         nv = vis.shape[1]
         x = jnp.concatenate([vis.astype(x.dtype), x[:, nv:]], axis=1)
-
     enc_out = None
     if cfg.enc_layers:
         enc_out = encode(params, cfg, batch["src_embeds"].astype(x.dtype), remat)
+    return x, enc_out
+
+
+def _sequence(params, cfg: ModelConfig, batch: dict, remat: str, cache_len: int):
+    """The stack over a whole sequence: (final hidden states, the layer
+    caches stacked over periods at a capacity of ``max(cache_len, S + 1)``,
+    the router aux loss of each period, the encoder's memory)."""
+    P, n_periods, pos_kinds, pos_moe = period_info(cfg)
+    x, enc_out = _embed_inputs(params, cfg, batch, remat)
+    S = x.shape[1]
+    cache_len = max(cache_len, S + 1)
+
+    def mix(bp, kind, h, _):
+        if kind == MAMBA:
+            return mamba_lib.mamba_forward(bp["mamba"], h, cfg.mamba, cfg.d_model)
+        acfg = _attn_cfg(cfg, kind)
+        h, kv = attn_lib.attention(bp["attn"], h, cfg_attn=acfg)
+        return h, _ring_from_prefill(kv, acfg, S, cache_len)
 
     def period_body(x, bps):
-        aux = jnp.zeros((), jnp.float32)
+        caches, aux = {}, jnp.zeros((), jnp.float32)
         for j in range(P):
-            x, a = _apply_block_train(bps[f"pos{j}"], cfg, pos_kinds[j], pos_moe[j], x, enc_out)
+            x, caches[f"pos{j}"], a = _apply_block(
+                bps[f"pos{j}"], cfg, pos_kinds[j], pos_moe[j], x, enc_out, mix,
+                moe_lib.moe_apply)
             aux = aux + a
         # constrain the carry OUTPUT: this is the buffer remat saves per
         # period — sharded (data, model) it is 16x smaller than replicated
-        return _constrain(x), aux
+        return _constrain(x), (caches, aux)
 
-    x, auxes = stack_scan(_remat_wrap(period_body, remat), _constrain(x),
-                          params["blocks"])
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = _logits(params, cfg, x)
-    return logits, jnp.sum(auxes)
+    x, (caches, auxes) = jax.lax.scan(_remat_wrap(period_body, remat),
+                                      _constrain(x), params["blocks"])
+    return rmsnorm(params["final_norm"], x, cfg.norm_eps), caches, auxes, enc_out
+
+
+def forward_train(params, cfg: ModelConfig, batch: dict, remat: str = "dots"):
+    """Returns (logits, router aux loss).  The decode caches the sequence
+    leaves go unused: under ``jax.jit`` their ops are dropped."""
+    x, _, auxes, _ = _sequence(params, cfg, batch, remat, cache_len=0)
+    return _logits(params, cfg, x), jnp.sum(auxes)
 
 
 def loss_fn(params, cfg: ModelConfig, batch: dict, remat: str = "dots"):
@@ -343,92 +336,22 @@ def loss_fn(params, cfg: ModelConfig, batch: dict, remat: str = "dots"):
     return ce + aux_w * aux, {"ce": ce, "aux": aux}
 
 
-# ---------------------------------------------------------------------------
-# Serving: prefill + decode with caches
-# ---------------------------------------------------------------------------
-def cache_specs(cfg: ModelConfig, batch: int, seq_len: int, enc_len: int = 0):
-    """ShapeDtypeStruct pytree for the decode cache (+ cross-attn memory)."""
-    dtype = model_dtype(cfg)
-    P, n_periods, pos_kinds, pos_moe = period_info(cfg)
+def prefill(params, cfg: ModelConfig, batch: dict, remat: str = "dots",
+            cache_len: int = 0):
+    """Full-sequence forward producing (last-position logits, decode cache).
 
-    def stack(spec):
-        return jax.tree_util.tree_map(
-            lambda s: jax.ShapeDtypeStruct((n_periods,) + s.shape, s.dtype), spec)
-
-    cache = {}
-    for j, kind in enumerate(pos_kinds):
-        if kind == MAMBA:
-            spec = mamba_lib.mamba_cache_spec(cfg.d_model, cfg.mamba, batch, dtype)
-        else:
-            spec = attn_lib.cache_spec(_attn_cfg(cfg, kind), batch, seq_len, dtype)
-        cache[f"pos{j}"] = stack(spec)
-    out = {"layers": cache, "pos": jax.ShapeDtypeStruct((), jnp.int32)}
+    The cache matches ``cache_specs(cfg, B, max(cache_len, S + 1))``:
+    attention layers get their K/V (ring-rolled to window size for
+    SWA/chunked kinds), SSD layers get {ssm state, conv tail}; enc-dec
+    additionally stores the encoder memory.
+    """
+    S = batch["tokens"].shape[1]
+    x, layer_caches, _, enc_out = _sequence(params, cfg, batch, remat, cache_len)
+    logits = _logits(params, cfg, x[:, -1:])
+    cache = {"layers": layer_caches, "pos": jnp.asarray(S, jnp.int32)}
     if cfg.enc_layers:
-        de = cfg.enc_d_model or cfg.d_model
-        out["enc_memory"] = jax.ShapeDtypeStruct((batch, enc_len, de), dtype)
-    return out
-
-
-def _apply_block_decode(bp, cfg: ModelConfig, kind: str, use_moe: bool, x, caches,
-                        layer, pos, enc_memory):
-    """One block of one decode step. ``caches`` holds this block position's
-    caches of every period stacked; the block reads and writes its own,
-    ``layer``, in place. Returns (x, caches)."""
-    h = rmsnorm(bp["norm1"], x, cfg.norm_eps)
-    if kind == MAMBA:
-        h, caches = mamba_lib.mamba_decode(
-            bp["mamba"], h, caches, layer, cfg.mamba, cfg.d_model)
-    else:
-        h, caches = attn_lib.attention_decode(
-            bp["attn"], h, caches, pos, layer, cfg_attn=_attn_cfg(cfg, kind))
-    x = x + _residual(cfg, h)
-    if cfg.cross_attn and enc_memory is not None:
-        h = rmsnorm(bp["norm_x"], x, cfg.norm_eps)
-        x = x + _cross_attention(bp["xattn"], h, enc_memory, cfg)
-    if cfg.d_ff > 0:
-        h = rmsnorm(bp["norm2"], x, cfg.norm_eps)
-        if use_moe:
-            h, _ = moe_lib.moe_ffn(
-                bp["moe"], h, num_experts=cfg.moe.num_experts, top_k=cfg.moe.top_k,
-                capacity_factor=cfg.moe.capacity_factor, act=cfg.mlp_act,
-                gated=cfg.mlp_gated, shared_expert=cfg.moe.shared_expert,
-                no_drop=True)
-        else:
-            h = mlp(bp["mlp"], h, act=cfg.mlp_act, gated=cfg.mlp_gated)
-        x = x + _residual(cfg, h)
-    return x, caches
-
-
-def decode_step(params, cfg: ModelConfig, token: jax.Array, cache: dict):
-    """token (B, 1) int32; cache from cache_specs/prefill. Returns (logits, cache).
-
-    The stacked layer caches ride in the layer scan's carry, so each layer
-    writes its token into them in place rather than the scan slicing every
-    layer's cache out and stacking a new one."""
-    P, n_periods, pos_kinds, pos_moe = period_info(cfg)
-    pos = cache["pos"]
-    x = _scale_embeddings(cfg, embed(params["embed"], token))
-    enc_memory = cache.get("enc_memory")
-
-    def period_body(carry, scanned):
-        x, caches = carry
-        bps, layer = scanned
-        caches = dict(caches)
-        for j in range(P):
-            x, caches[f"pos{j}"] = _apply_block_decode(
-                bps[f"pos{j}"], cfg, pos_kinds[j], pos_moe[j], x, caches[f"pos{j}"],
-                layer, pos, enc_memory)
-        return (x, caches), None
-
-    (x, new_layer_caches), _ = stack_scan(
-        period_body, (x, cache["layers"]),
-        (params["blocks"], jnp.arange(n_periods, dtype=jnp.int32)))
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = _logits(params, cfg, x)
-    new_cache = dict(cache)
-    new_cache["layers"] = new_layer_caches
-    new_cache["pos"] = pos + 1
-    return logits, new_cache
+        cache["enc_memory"] = enc_out
+    return logits, cache
 
 
 def _ring_from_prefill(kv: dict, cfg_attn: dict, S: int, cache_len: int):
@@ -458,63 +381,76 @@ def _ring_from_prefill(kv: dict, cfg_attn: dict, S: int, cache_len: int):
     return {"k": ring(kv["k"]), "v": ring(kv["v"])}
 
 
-def prefill(params, cfg: ModelConfig, batch: dict, remat: str = "dots",
-            cache_len: int = 0):
-    """Full-sequence forward producing (last-position logits, decode cache).
-
-    The cache matches ``cache_specs(cfg, B, S)`` exactly: attention layers get
-    their K/V (ring-rolled to window size for SWA/chunked kinds), SSD layers
-    get {ssm state, conv tail}; enc-dec additionally stores the encoder memory.
-    """
+# ---------------------------------------------------------------------------
+# Serving: prefill + decode with caches
+# ---------------------------------------------------------------------------
+def cache_specs(cfg: ModelConfig, batch: int, seq_len: int, enc_len: int = 0):
+    """ShapeDtypeStruct pytree for the decode cache (+ cross-attn memory)."""
+    dtype = model_dtype(cfg)
     P, n_periods, pos_kinds, pos_moe = period_info(cfg)
-    tokens = batch["tokens"]
-    B, S = tokens.shape
-    cache_len = max(cache_len, S + 1)
-    x = _scale_embeddings(cfg, embed(params["embed"], tokens))
-    if cfg.vision_tokens and "vision_embeds" in batch:
-        vis = batch["vision_embeds"] @ params["vision_proj"]
-        nv = vis.shape[1]
-        x = jnp.concatenate([vis.astype(x.dtype), x[:, nv:]], axis=1)
-    enc_out = None
-    if cfg.enc_layers:
-        enc_out = encode(params, cfg, batch["src_embeds"].astype(x.dtype), remat)
 
-    def period_body(x, bps):
-        caches = {}
-        for j in range(P):
-            kind, use_moe = pos_kinds[j], pos_moe[j]
-            bp = bps[f"pos{j}"]
-            h = rmsnorm(bp["norm1"], x, cfg.norm_eps)
+    def stack(spec):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct((n_periods,) + s.shape, s.dtype), spec)
+
+    cache = {}
+    for j, kind in enumerate(pos_kinds):
+        if kind == MAMBA:
+            spec = mamba_lib.mamba_cache_spec(cfg.d_model, cfg.mamba, batch, dtype)
+        else:
+            spec = attn_lib.cache_spec(_attn_cfg(cfg, kind), batch, seq_len, dtype)
+        cache[f"pos{j}"] = stack(spec)
+    out = {"layers": cache, "pos": jax.ShapeDtypeStruct((), jnp.int32)}
+    if cfg.enc_layers:
+        de = cfg.enc_d_model or cfg.d_model
+        out["enc_memory"] = jax.ShapeDtypeStruct((batch, enc_len, de), dtype)
+    return out
+
+
+def decode_step(params, cfg: ModelConfig, token: jax.Array, cache: dict):
+    """token (B, 1) int32; cache from cache_specs/prefill. Returns (logits, cache).
+
+    The stacked layer caches ride in the layer scan's carry, so each layer
+    writes its token into them in place rather than the scan slicing every
+    layer's cache out and stacking a new one."""
+    P, n_periods, pos_kinds, pos_moe = period_info(cfg)
+    pos = cache["pos"]
+    x = _scale_embeddings(cfg, embed(params["embed"], token))
+    enc_memory = cache.get("enc_memory")
+
+    # Decode routes every token to its experts.  Train and prefill drop the
+    # tokens over an expert's capacity, so where they drop any, an MoE
+    # model's decode does not continue its prefill exactly.
+    moe = partial(moe_lib.moe_ffn, no_drop=True)
+
+    def period_body(carry, scanned):
+        x, caches = carry
+        bps, layer = scanned
+
+        def mix(bp, kind, h, stack):
+            # ``stack`` holds this block position's caches of every period;
+            # the layer reads and writes its own, ``layer``, in place
             if kind == MAMBA:
-                h, cache_j = mamba_lib.mamba_forward(
-                    bp["mamba"], h, cfg.mamba, cfg.d_model, return_cache=True)
-            else:
-                acfg = _attn_cfg(cfg, kind)
-                h, kv = attn_lib.attention_prefill(bp["attn"], h, cfg_attn=acfg)
-                cache_j = _ring_from_prefill(kv, acfg, S, cache_len)
-            caches[f"pos{j}"] = cache_j
-            x = x + _residual(cfg, h)
-            if cfg.cross_attn and enc_out is not None:
-                hx = rmsnorm(bp["norm_x"], x, cfg.norm_eps)
-                x = x + _cross_attention(bp["xattn"], hx, enc_out, cfg)
-            if cfg.d_ff > 0:
-                h2 = rmsnorm(bp["norm2"], x, cfg.norm_eps)
-                if use_moe:
-                    h2, _ = moe_lib.moe_apply(
-                        bp["moe"], h2, num_experts=cfg.moe.num_experts,
-                        top_k=cfg.moe.top_k, capacity_factor=cfg.moe.capacity_factor,
-                        act=cfg.mlp_act, gated=cfg.mlp_gated,
-                        shared_expert=cfg.moe.shared_expert)
-                else:
-                    h2 = mlp(bp["mlp"], h2, act=cfg.mlp_act, gated=cfg.mlp_gated)
-                x = x + _residual(cfg, h2)
-        return _constrain(x), caches
+                return mamba_lib.mamba_decode(
+                    bp["mamba"], h, stack, layer, cfg.mamba, cfg.d_model)
+            return attn_lib.attention_decode(
+                bp["attn"], h, stack, pos, layer, cfg_attn=_attn_cfg(cfg, kind))
 
-    x, layer_caches = stack_scan(_remat_wrap(period_body, remat), _constrain(x),
-                                 params["blocks"])
+        caches = dict(caches)
+        for j in range(P):
+            x, caches[f"pos{j}"], _ = _apply_block(
+                bps[f"pos{j}"], cfg, pos_kinds[j], pos_moe[j], x, enc_memory, mix,
+                moe, caches[f"pos{j}"])
+        return (x, caches), None
+
+    (x, new_layer_caches), _ = jax.lax.scan(
+        period_body, (x, cache["layers"]),
+        (params["blocks"], jnp.arange(n_periods, dtype=jnp.int32)))
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = _logits(params, cfg, x[:, -1:])
-    cache = {"layers": layer_caches, "pos": jnp.asarray(S, jnp.int32)}
-    if cfg.enc_layers:
-        cache["enc_memory"] = enc_out
-    return logits, cache
+    logits = _logits(params, cfg, x)
+    new_cache = dict(cache)
+    new_cache["layers"] = new_layer_caches
+    new_cache["pos"] = pos + 1
+    return logits, new_cache
+
+

@@ -43,26 +43,6 @@ def get_moe_specs() -> Optional[dict]:
     return _MOE_SPECS
 
 
-# generic named constraint points (SSD head sharding, etc.)
-_NAMED_SPECS: dict = {}
-
-
-def set_named_specs(specs: Optional[dict]) -> None:
-    global _NAMED_SPECS
-    _NAMED_SPECS = specs or {}
-
-
-def named_specs_installed() -> bool:
-    return bool(_NAMED_SPECS)
-
-
-def constrain_named(name: str, x):
-    s = _NAMED_SPECS.get(name)
-    if s is None:
-        return x
-    return jax.lax.with_sharding_constraint(x, s)
-
-
 def constrain_moe(name: str, x):
     if not _MOE_SPECS or name not in _MOE_SPECS:
         return x
@@ -82,28 +62,3 @@ def constrain_moe(name: str, x):
     # NamedSharding works with or without an ambient mesh context
     return jax.lax.with_sharding_constraint(
         x, NamedSharding(_MOE_SPECS["mesh"], fixed))
-
-
-# §Perf variant switch read by the launchers when installing MoE specs
-_MOE_GATHER_QUANT = False
-
-
-def set_moe_gather_quant(v: bool) -> None:
-    global _MOE_GATHER_QUANT
-    _MOE_GATHER_QUANT = bool(v)
-
-
-def get_moe_gather_quant() -> bool:
-    return _MOE_GATHER_QUANT
-
-
-_MOE_IMPL_OVERRIDE = None
-
-
-def set_moe_impl_override(v) -> None:
-    global _MOE_IMPL_OVERRIDE
-    _MOE_IMPL_OVERRIDE = v
-
-
-def get_moe_impl_override():
-    return _MOE_IMPL_OVERRIDE

@@ -27,10 +27,6 @@ def data_axes(mesh: Mesh):
 DATA_AXES = data_axes  # alias
 
 
-# §Perf variant: disable tensor parallelism entirely (small models: TP
-# all-reduces of activation cotangents dwarf the weights — pure FSDP wins)
-NO_TP = False
-
 # (regex on '/'-joined path, trailing-dims partition tuple)
 _PARAM_RULES = [
     # embedding table sharded on the FEATURE dim: a gather whose rows are
@@ -82,8 +78,6 @@ def maybe_axis(dim: int, axis: Optional[str], mesh: Mesh):
 def _spec_for(path_s: str, shape, mesh: Mesh, extra_leading: int = 0) -> P:
     for pat, trailing in _PARAM_RULES:
         if re.search(pat, path_s):
-            if NO_TP:
-                trailing = tuple(None if t == "model" else t for t in trailing)
             nt = len(trailing)
             # conv_b / scalars: trailing rule may be longer than shape
             trailing = trailing[-min(nt, len(shape) - extra_leading):]
@@ -159,12 +153,8 @@ def opt_state_specs(params, pspecs, mesh: Mesh, zero1: bool = True):
 
 def batch_specs(batch_shapes: dict, mesh: Mesh, group_stacked: bool = False,
                 axes=None):
-    """Specs for input batches: leading batch dim over (pod, data) — or over
-    ALL axes (incl. 'model') in NO_TP mode, where every device is a pure
-    data-parallel worker."""
+    """Specs for input batches: leading batch dim over (pod, data)."""
     daxes = axes if axes is not None else data_axes(mesh)
-    if axes is None and NO_TP:
-        daxes = daxes + ("model",)
     ax = daxes if len(daxes) > 1 else daxes[0]
 
     def one(leaf):
